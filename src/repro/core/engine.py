@@ -89,6 +89,58 @@ def _kernel_backend():
     return _KOPS, _JNP
 
 
+class _RowMinima:
+    """Pooled select for server-specific scores under ``tie="low"``, kept
+    from per-row minima instead of a full N x J scan per grant.
+
+    :class:`~repro.core.policies.PooledPolicy` picks the first row-major
+    index within ``atol`` of the global minimum.  A row holds such an index
+    exactly when its row minimum does, so the first such row, and then the
+    first such column in it, is the same pick.  A grant at (n, j) changes
+    only row n and column j (see :meth:`BatchedEpoch.apply`), so the minima
+    stay exact from the new column j, a per-row count of the columns at the
+    minimum, and a rescan of row n and of every row whose last minimal
+    column rose."""
+
+    def __init__(self, n_rows: int):
+        self.rmin = np.full(n_rows, np.inf)
+        self.cnt = np.zeros(n_rows, np.int64)   # columns equal to rmin
+        self.stale = np.ones(n_rows, bool)      # rows to rescan
+
+    @staticmethod
+    def column(s, feas, j: int) -> np.ndarray:
+        return np.where(feas[:, j], s[:, j], np.inf)
+
+    def update(self, n: int, old: np.ndarray, new: np.ndarray) -> None:
+        """Column j went from ``old`` to ``new``; row n changed whole."""
+        rmin = self.rmin
+        self.cnt += (new == rmin) & (old != rmin)
+        self.cnt -= (old == rmin) & (new > rmin)
+        lower = new < rmin
+        self.rmin = np.where(lower, new, rmin)
+        self.cnt[lower] = 1
+        self.stale |= self.cnt == 0
+        self.stale[n] = True
+
+    def select(self, s, feas) -> Optional[tuple[int, int]]:
+        """The pooled pick, or None when no finite score is left (the
+        caller then decides with the full scan)."""
+        rows = np.flatnonzero(self.stale)
+        if rows.size:
+            masked = np.where(feas[rows], s[rows], np.inf)
+            self.rmin[rows] = masked.min(axis=1)
+            self.cnt[rows] = (masked == self.rmin[rows][:, None]).sum(axis=1)
+            self.stale[rows] = False
+        m = self.rmin.min()
+        if not np.isfinite(m):
+            return None
+        n = int(np.flatnonzero(np.isclose(self.rmin, m, rtol=0,
+                                          atol=1e-12))[0])
+        row = np.where(feas[n], s[n], np.inf)
+        j = int(np.flatnonzero(np.isclose(row, m, rtol=0, atol=1e-12))[0])
+        return n, j
+
+
 class BatchedEpoch:
     """Incremental scorer + selector for one allocation epoch.
 
@@ -169,6 +221,8 @@ class BatchedEpoch:
         self._init_scores()
         self.feas = criteria.feasible_mask(
             self.TD, self.FREE, self.allowed, self.tot < self.wanted)
+        self._rowmin = (_RowMinima(N) if policy == "pooled" and tie == "low"
+                        and self.crit.server_specific else None)
 
     # -- scoring --------------------------------------------------------------
 
@@ -229,6 +283,10 @@ class BatchedEpoch:
         """Next (framework, server) pick, or None when the epoch is done."""
         if self.kernel:
             return self._select_kernel()
+        if self._rowmin is not None:
+            pick = self._rowmin.select(self.s, self.feas)
+            if pick is not None:
+                return pick
         if not self.feas.any():
             return None
         return self.policy.select(
@@ -281,6 +339,9 @@ class BatchedEpoch:
                 self._kd[n] = _KBIG
                 self._dev_kd = self._dev_kd.at[n].set(_KBIG)
             return
+        rm = self._rowmin
+        if rm is not None:
+            old_col = rm.column(self.s, self.feas, j)
         # feasibility: column j saw FREE change; row n may have hit `wanted`
         wants = self.tot < self.wanted
         self.feas[:, j] = (
@@ -292,3 +353,8 @@ class BatchedEpoch:
         if not wants[n]:
             self.feas[n, :] = False
         self._refresh_scores(n, j, demand_changed)
+        if rm is not None:
+            if demand_changed:          # every score moved: rescan all rows
+                self._rowmin = _RowMinima(len(self.tot))
+            else:
+                rm.update(n, old_col, rm.column(self.s, self.feas, j))

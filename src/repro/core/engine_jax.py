@@ -497,7 +497,6 @@ def epoch_loop_mesh(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
     """
     global MESH_TRACE_COUNT
     MESH_TRACE_COUNT += 1
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
     from repro.launch.mesh import make_agent_mesh
 
@@ -772,7 +771,7 @@ def epoch_loop_mesh(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
     shard_row = P("agents", None)    # (J, R) blocks
     rep = P()
     s_spec = shard_j if server_specific else rep
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_body, mesh=make_agent_mesh(K),
         in_specs=(shard_j, shard_row, shard_row, shard_j, s_spec, shard_j,
                   shard_j, shard_row, P("agents"),
@@ -782,7 +781,7 @@ def epoch_loop_mesh(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
                   rep),
         out_specs=(rep, rep, rep, shard_j, rep, shard_row, P("agents"),
                    rep, rep),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(X, FREE, cap0, dom0, s0, feas0, allowed, C,
               used.astype(jnp.int32), D, TD, phi, wanted,
@@ -813,6 +812,40 @@ def _jitted_mesh():
     # no donation: the sharded buffers live per-device and the RRR replay
     # path re-dispatches from kept (non-invalidated) input references.
     return jax.jit(epoch_loop_mesh, static_argnames=_STATIC_MESH)
+
+
+class EpochCompileError(RuntimeError):
+    """The compiler refused an epoch program: a Mosaic kernel it cannot
+    build, a program larger than device memory, a scoped-VMEM overflow.
+    This happens before anything reaches the device, so it is a fault of
+    the program, never of the device: the self-healing dispatch does not
+    retry it or re-run the epoch on the host
+    (:func:`repro.core.faults.is_device_fault`)."""
+
+
+#: compiled epoch executables, keyed by jitted function, static arguments
+#: and the type and placement of every array argument (shapes are bucketed,
+#: so this stays as small as jit's own cache would)
+_EXECUTABLES: dict = {}
+
+
+def _executable(fn, args, static):
+    """The compiled executable of ``fn`` for ``args``, compiled ahead of
+    the launch.  Compiling apart from running is what tells a compiler
+    refusal (an :class:`EpochCompileError`, or the tracing error itself)
+    from a fault of the device, which can only show when the executable
+    runs or its result is read back."""
+    key = (fn, tuple(sorted(static.items())),
+           tuple((jax.typeof(a), getattr(a, "sharding", None)) for a in args))
+    exe = _EXECUTABLES.get(key)
+    if exe is None:
+        try:
+            exe = fn.lower(*args, **static).compile()
+        except jax.errors.JaxRuntimeError as exc:
+            raise EpochCompileError(
+                f"the compiler refused the epoch program: {exc}") from exc
+        _EXECUTABLES[key] = exe
+    return exe
 
 
 def _bucket(n: int, lo: int = 8) -> int:
@@ -913,27 +946,19 @@ class _EpochRun:
             # grow-and-replay instead of a host snapshot.
             self._last_inputs = (X_cur, FREE_cur, used_cur)
         dD, dTD, dC, dphi, dwanted, dallowed = self.consts
-        if self.devices > 1:
-            self.pending = self.fn(
-                X_cur, dD, dTD, dC, FREE_cur, dphi, dwanted, dallowed,
+        args = (X_cur, dD, dTD, dC, FREE_cur, dphi, dwanted, dallowed,
                 jnp.asarray(self.perms), used_cur,
                 np.int32(self.pidx), np.int32(self.pos),
-                jnp.int32(self.J), self.limit, jnp.float32(self.eps),
-                kind=self.kind, policy=self.policy,
-                lookahead=self.lookahead, use_limit=self.use_limit,
-                max_steps=self.max_steps, devices=self.devices,
-            )
-            return
-        self.pending = self.fn(
-            X_cur, dD, dTD, dC, FREE_cur, dphi, dwanted, dallowed,
-            jnp.asarray(self.perms), used_cur,
-            np.int32(self.pidx), np.int32(self.pos),
-            jnp.int32(self.J), self.limit, jnp.float32(self.eps),
-            kind=self.kind, policy=self.policy, lookahead=self.lookahead,
-            use_limit=self.use_limit, use_pallas=self.use_pallas,
-            interpret=self.interpret, max_steps=self.max_steps,
-            shards=self.shards,
-        )
+                jnp.int32(self.J), self.limit, jnp.float32(self.eps))
+        static = dict(kind=self.kind, policy=self.policy,
+                      lookahead=self.lookahead, use_limit=self.use_limit,
+                      max_steps=self.max_steps)
+        if self.devices > 1:
+            static["devices"] = self.devices
+        else:
+            static.update(use_pallas=self.use_pallas,
+                          interpret=self.interpret, shards=self.shards)
+        self.pending = _executable(self.fn, args, static)(*args)
 
     def _finish(self) -> list[tuple[int, int]]:
         out: list[tuple[int, int]] = []
@@ -1032,8 +1057,9 @@ def run_epoch_async(criterion, policy: str, *, X, D, C, FREE, phi, allowed,
     docstring); it is rounded down to a power of two dividing the padded
     shapes.  ``devices > 1`` dispatches :func:`epoch_loop_mesh` instead —
     the server axis sharded over that many REAL devices (rounded down to a
-    power of two within the process device count; ``shards``/``use_pallas``
-    do not apply there, each device is one resident shard).  ``use_pallas``
+    power of two; more devices than the process has is a ``ValueError``;
+    ``shards``/``use_pallas`` do not apply there, each device is one
+    resident shard).  ``use_pallas``
     is strictly opt-in (exact-tie caveat in the module docstring);
     ``use_pallas="persistent"`` runs the whole epoch as one persistent
     Pallas kernel instance (``repro.kernels.epoch_persistent``).
@@ -1052,7 +1078,10 @@ def run_epoch_async(criterion, policy: str, *, X, D, C, FREE, phi, allowed,
     if kind not in COVERED_CRITERIA or policy not in COVERED_POLICIES:
         raise ValueError(f"fused epoch does not cover {kind}/{policy}")
     interpret = jax.default_backend() == "cpu"
-    devices = max(1, min(int(devices), len(jax.devices())))
+    if int(devices) > len(jax.devices()):
+        raise ValueError(f"epoch asks for a {devices}-device mesh; this "
+                         f"process has {len(jax.devices())} devices")
+    devices = max(1, int(devices))
     devices = 1 << (devices.bit_length() - 1)    # floor to a power of two
     if devices > 1:
         shards = 1          # each mesh device IS one resident shard

@@ -32,6 +32,7 @@ see tests/test_chaos.py).
 from __future__ import annotations
 
 import dataclasses
+import sys
 from typing import Optional
 
 import numpy as np
@@ -50,6 +51,25 @@ class DispatchTimeout(InjectedDispatchError):
     error: the fused epoch path cannot preempt a blocking device call, so
     a timeout is only ever *observed* (by a watchdog or injector), never
     interrupted — recovery re-runs the epoch, it does not cancel it."""
+
+
+def is_device_fault(exc: BaseException) -> bool:
+    """May the self-healing dispatch retry ``exc`` and re-run on the host?
+
+    Only device faults at run time qualify: an injected fault, or a JAX
+    runtime error raised while a compiled epoch runs or is read back.
+    Anything else raised while staging a dispatch — a tracing or lowering
+    error, a compiler refusal (the engine compiles ahead of the launch and
+    raises ``engine_jax.EpochCompileError``, which is no JAX runtime
+    error), a refused configuration or device count — is not the device's
+    fault, and hiding it behind a host re-run would turn a program the
+    chip cannot run into a silent CPU run; it propagates.  (A JAX runtime
+    error can only exist once jax is imported, so the numpy-only path
+    never imports it to ask.)"""
+    if isinstance(exc, InjectedFault):
+        return True
+    jax = sys.modules.get("jax")
+    return jax is not None and isinstance(exc, jax.errors.JaxRuntimeError)
 
 
 # ---------------------------------------------------------------------------

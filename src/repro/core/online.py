@@ -1098,8 +1098,10 @@ class OnlineAllocator:
         ``shards``/``devices`` requests only at or above their measured
         floors (:data:`repro.core.engine.AUTO_SHARD_MIN_CELLS` /
         :data:`~repro.core.engine.AUTO_MESH_MIN_CELLS`) and collapses them
-        to the plain fused dispatch below.  Explicit ``use_kernel`` specs
-        are a stated choice and pass through untouched — EXCEPT while the
+        to the plain fused dispatch below; it also fits a mesh request to
+        the devices the process has.  Explicit ``use_kernel`` specs are a
+        stated choice and pass through untouched (the engine refuses a mesh
+        larger than the process) — EXCEPT while the
         device path is quarantined (see :class:`~repro.core.faults
         .DeviceHealth`): a failing device mesh degrades to a single device
         on every path until a probe epoch succeeds (health trumps sizing).
@@ -1113,6 +1115,10 @@ class OnlineAllocator:
             shards = 1
         if devices > 1 and cells < AUTO_MESH_MIN_CELLS:
             devices = 1
+        if devices > 1:
+            import jax
+
+            devices = min(devices, len(jax.devices()))
         return shards, devices
 
     def begin_epoch(self, per_agent_limit: Optional[int] = None,
@@ -1223,8 +1229,15 @@ class OnlineAllocator:
         if kernel == "fused":
             shards, devices = self._resolve_partition(
                 use_kernel, N, len(view.agents), shards, devices)
-            handle = self._dispatch_fused(view, TD, per_agent_limit,
-                                          shards, devices, preperms)
+            try:
+                handle = self._dispatch_fused(view, TD, per_agent_limit,
+                                              shards, devices, preperms)
+            except Exception:
+                # not a device fault (see faults.is_device_fault): surface
+                # it with the epoch undone — stream rewound, bracket closed
+                self.rng.bit_generator.state = rng_state0
+                self._journal_abort()
+                raise
             if handle is not None:
                 epoch = InFlightEpoch(view=view, TD=TD,
                                       per_agent_limit=per_agent_limit,
@@ -1294,7 +1307,16 @@ class OnlineAllocator:
         if epoch.cached_seq is not None:   # epoch-cache hit: replay
             grants = self._apply_seq(epoch.view, epoch.TD, epoch.cached_seq)
         else:
-            grants = self._commit_fused(epoch)
+            try:
+                grants = self._commit_fused(epoch)
+            except Exception:
+                # not a device fault (device faults heal inside): a chained
+                # or replayed dispatch that could not be built.  Surface it
+                # with the epoch undone, as begin_epoch does at dispatch.
+                if epoch.rng_state0 is not None:
+                    self.rng.bit_generator.state = epoch.rng_state0
+                self._journal_abort()
+                raise
         self._journal_commit(grants)
         if self.audit:
             _invariants.assert_invariants(self)
@@ -1304,12 +1326,14 @@ class OnlineAllocator:
 
     def _dispatch_fused(self, view, TD, per_agent_limit, shards, devices,
                         preperms):
-        """Dispatch the fused device epoch, retrying transient failures with
+        """Dispatch the fused device epoch, retrying device faults with
         capped exponential backoff (:class:`~repro.core.faults
         .RecoveryPolicy`).  Returns the :class:`EpochHandle`, or ``None``
         after retries are exhausted — the caller then self-heals on the
         host engine.  Each attempt restores the rng to its own pre-attempt
-        position so a failed dispatch consumes no stream."""
+        position so a failed dispatch consumes no stream.  Any other error
+        propagates on its first occurrence (:func:`~repro.core.faults
+        .is_device_fault`)."""
         from repro.core import engine_jax
 
         pol = self.recovery
@@ -1332,6 +1356,8 @@ class OnlineAllocator:
                     devices=devices, preperms=preperms,
                 )
             except Exception as exc:
+                if not _faults.is_device_fault(exc):
+                    raise
                 self.rng.bit_generator.state = state
                 self.fault_stats.dispatch_failures += 1
                 self._notify_fault("dispatch-error", error=repr(exc),
@@ -1349,14 +1375,17 @@ class OnlineAllocator:
         return None
 
     def _commit_fused(self, epoch: InFlightEpoch) -> list[Grant]:
-        """Block on the device result and apply it; a failure (XLA error,
-        injected fault, timeout) enters :meth:`_recover_commit`."""
+        """Block on the device result and apply it; a device fault (XLA
+        runtime error, injected fault, timeout) enters
+        :meth:`_recover_commit`, anything else propagates."""
         inj = self.fault_injector
         try:
             if inj is not None and inj.take_commit_fault():
                 raise inj.error("commit")
             seq = epoch.handle.result()
         except Exception as exc:
+            if not _faults.is_device_fault(exc):
+                raise
             return self._recover_commit(epoch, exc)
         if self.device_health.on_success():
             self._notify_fault("probe-success",
@@ -1406,6 +1435,8 @@ class OnlineAllocator:
                 handle = self._redispatch(epoch)
                 seq = handle.result()
             except Exception as exc2:
+                if not _faults.is_device_fault(exc2):
+                    raise
                 self.fault_stats.dispatch_failures += 1
                 self._notify_fault("dispatch-error", error=repr(exc2),
                                    attempt=attempt + 1)

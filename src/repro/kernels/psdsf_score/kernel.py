@@ -14,7 +14,10 @@ kernel keeps each (BN, BJ) score tile in VMEM and reduces it to a per-tile
 size #tiles only.
 
 Tiling: grid (N/BN, J/BJ); the R axis (<= 8 resources) is unrolled in
-registers, so tiles are clean (BN, BJ) = (128, 128) VPU shapes.
+registers, so tiles are clean (BN, BJ) = (128, 128) VPU shapes.  Each
+tile's (min, argmin) pair is a scalar written to scalar memory (SMEM): a
+(1, 1) block of a (tiles_n, tiles_j) array in VMEM is not (8, 128)-aligned,
+and the TPU compiler refuses it.
 
 Beyond the fully-fused rPS-DSF+pooled reduction, the family also covers the
 other criterion x policy combinations of the device-resident epoch engine
@@ -35,9 +38,28 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BIG = 3.4e38  # feasibility/overflow sentinel (~f32 max); python float so the
               # kernel body doesn't capture a traced constant
+_IBIG = 2**31 - 1
+
+
+def _tile_argmin(masked, cols: int):
+    """(min, local row, local col) of a 2-D tile: the FIRST row-major
+    index attaining the minimum, as ``jnp.argmin`` over the flattened tile
+    picks it — computed from full reductions and an iota, which the TPU
+    lowering supports (a flat reshape plus a dynamic gather is not)."""
+    m = jnp.min(masked)
+    lin = (jax.lax.broadcasted_iota(jnp.int32, masked.shape, 0) * cols
+           + jax.lax.broadcasted_iota(jnp.int32, masked.shape, 1))
+    first = jnp.min(jnp.where(masked == m, lin, _IBIG))
+    return m, first // cols, first % cols
+
+
+#: the whole (tiles_n, tiles_j) output stays resident in SMEM for the grid;
+#: each cell writes its own scalar (see the module docstring)
+_SCALAR_OUT = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _score_tile_kernel(x_ref, phi_ref, d_ref, res_ref, min_ref, arg_ref, *,
@@ -51,8 +73,8 @@ def _score_tile_kernel(x_ref, phi_ref, d_ref, res_ref, min_ref, arg_ref, *,
     feas = jnp.ones((bn, bj), jnp.bool_)
     # unrolled resource loop: everything stays (BN, BJ)
     for r in range(n_res):
-        d_r = d_ref[:, r][:, None]     # (BN, 1)
-        res_r = res_ref[:, r][None, :]  # (1, BJ)
+        d_r = d_ref[:, r:r + 1]        # (BN, 1)
+        res_r = res_ref[r:r + 1, :]    # (1, BJ): res enters transposed
         ok = res_r > 0.0
         frac = jnp.where(ok, d_r / jnp.where(ok, res_r, 1.0), BIG)
         frac = jnp.where((d_r == 0.0) & ~ok, 0.0, frac)
@@ -60,13 +82,9 @@ def _score_tile_kernel(x_ref, phi_ref, d_ref, res_ref, min_ref, arg_ref, *,
         feas = feas & (d_r <= res_r)
     score = (x / phi) * dom
     score = jnp.where(feas, score, BIG)
-    # local argmin over the tile
-    flat = score.reshape(-1)
-    idx = jnp.argmin(flat)
-    ln = idx // bj
-    lj = idx % bj
-    min_ref[0, 0] = flat[idx]
-    arg_ref[0, 0] = (i * bn + ln) * jnp.int32(pl.num_programs(1) * bj) + (j * bj + lj)
+    m, ln, lj = _tile_argmin(score, bj)
+    min_ref[i, j] = m
+    arg_ref[i, j] = (i * bn + ln) * jnp.int32(pl.num_programs(1) * bj) + (j * bj + lj)
 
 
 def _masked_argmin1d_kernel(s_ref, ok_ref, min_ref, arg_ref, *, bn: int):
@@ -79,12 +97,10 @@ def _masked_argmin1d_kernel(s_ref, ok_ref, min_ref, arg_ref, *, bn: int):
         against row-level feasibility (does framework n fit ANYWHERE).
     """
     i = pl.program_id(0)
-    s = s_ref[...][:, 0]                      # (BN,)
-    ok = ok_ref[...][:, 0] != 0
-    masked = jnp.where(ok, s, BIG)
-    idx = jnp.argmin(masked)
-    min_ref[0, 0] = masked[idx]
-    arg_ref[0, 0] = i * bn + idx.astype(jnp.int32)
+    masked = jnp.where(ok_ref[...] != 0, s_ref[...], BIG)   # (BN, 1)
+    m, ln, _ = _tile_argmin(masked, 1)
+    min_ref[i, 0] = m
+    arg_ref[i, 0] = i * bn + ln
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
@@ -102,10 +118,7 @@ def masked_argmin1d_tiles(s, ok, *, bn: int = 128, interpret: bool = False):
             pl.BlockSpec((bn, 1), lambda i: (i, 0)),
             pl.BlockSpec((bn, 1), lambda i: (i, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
+        out_specs=[_SCALAR_OUT, _SCALAR_OUT],
         out_shape=[
             jax.ShapeDtypeStruct((tn, 1), jnp.float32),
             jax.ShapeDtypeStruct((tn, 1), jnp.int32),
@@ -122,15 +135,10 @@ def _masked_argmin2d_kernel(s_ref, feas_ref, min_ref, arg_ref, *,
     engine keeps s and feas consistent; this kernel only reduces them)."""
     i = pl.program_id(0)
     j = pl.program_id(1)
-    s = s_ref[...]
-    feas = feas_ref[...] != 0
-    masked = jnp.where(feas, s, BIG)
-    flat = masked.reshape(-1)
-    idx = jnp.argmin(flat)
-    ln = idx // bj
-    lj = idx % bj
-    min_ref[0, 0] = flat[idx]
-    arg_ref[0, 0] = (i * bn + ln) * jnp.int32(pl.num_programs(1) * bj) + (j * bj + lj)
+    masked = jnp.where(feas_ref[...] != 0, s_ref[...], BIG)
+    m, ln, lj = _tile_argmin(masked, bj)
+    min_ref[i, j] = m
+    arg_ref[i, j] = (i * bn + ln) * jnp.int32(pl.num_programs(1) * bj) + (j * bj + lj)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bj", "interpret"))
@@ -153,10 +161,7 @@ def masked_argmin2d_tiles(s, feas, *, bn: int = 128, bj: int = 128,
             pl.BlockSpec((bn, bj), lambda i, j: (i, j)),
             pl.BlockSpec((bn, bj), lambda i, j: (i, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        ],
+        out_specs=[_SCALAR_OUT, _SCALAR_OUT],
         out_shape=[
             jax.ShapeDtypeStruct((tn, tj), jnp.float32),
             jax.ShapeDtypeStruct((tn, tj), jnp.int32),
@@ -184,16 +189,13 @@ def psdsf_argmin_tiles(x, phi, d, res, *, bn: int = 128, bj: int = 128,
             pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((bn, R), lambda i, j: (i, 0)),
-            pl.BlockSpec((bj, R), lambda i, j: (j, 0)),
+            pl.BlockSpec((R, bj), lambda i, j: (0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        ],
+        out_specs=[_SCALAR_OUT, _SCALAR_OUT],
         out_shape=[
             jax.ShapeDtypeStruct((tn, tj), jnp.float32),
             jax.ShapeDtypeStruct((tn, tj), jnp.int32),
         ],
         interpret=interpret,
     )(x[:, None].astype(jnp.float32), phi[:, None].astype(jnp.float32),
-      d.astype(jnp.float32), res.astype(jnp.float32))
+      d.astype(jnp.float32), res.T.astype(jnp.float32))

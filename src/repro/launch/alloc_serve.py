@@ -37,6 +37,7 @@ from repro.core import invariants as _invariants
 from repro.core import journal as _journal
 from repro.core import metrics as _metrics
 from repro.core.online import OnlineAllocator
+from repro.launch import compile_cache
 
 #: demand vectors in quarter multiples (binary-exact f32/f64 arithmetic —
 #: release/re-register round-trips reproduce the profile bit-for-bit, the
@@ -142,11 +143,11 @@ class AllocatorService:
         return True
 
     def _run_epoch_with_retry(self) -> list:
-        """One epoch through begin/commit; on failure abort the in-flight
-        epoch (rng rewound — the retry re-draws the same stream) and retry
-        with backoff.  The allocator's own self-healing (device retries,
-        host fallback, quarantine) runs underneath; this layer only covers
-        errors that escape it."""
+        """One epoch through begin/commit; on a device fault that escapes
+        the allocator's own self-healing (device retries, host fallback,
+        quarantine), abort the in-flight epoch (rng rewound — the retry
+        re-draws the same stream) and retry with backoff.  Any other error
+        (:func:`~repro.core.faults.is_device_fault`) propagates at once."""
         last = None
         for attempt in range(self.max_retries + 1):
             if attempt:
@@ -158,6 +159,8 @@ class AllocatorService:
                     self.alloc.begin_epoch(use_kernel=self.use_kernel))
             except Exception as exc:
                 self.alloc.abort_epoch()
+                if not _faults.is_device_fault(exc):
+                    raise
                 last = exc
         self.epoch_failures += 1
         raise last
@@ -610,6 +613,7 @@ def main(argv: Optional[Sequence[str]] = None) -> dict:
                          "restart on the same --state-dir, assert recovered "
                          "ledger invariants + a warm-cache repeat hit")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.kill_restart_smoke:
         return kill_restart_smoke(args.state_dir or "serve-state",

@@ -22,6 +22,7 @@ from repro.cluster.gang import (
     GangScheduler, JobSpec, SLICE_TYPES, demand_from_dryrun, slice_agents,
 )
 from repro.core import metrics
+from repro.launch import compile_cache
 from repro.core.workloads import gang_arrivals
 
 
@@ -122,6 +123,7 @@ def main():
                     help="event-driven gang-arrival replay with fairness "
                          "telemetry (batched engine)")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.des:
         print("== DES replay: gang-job arrival stream, fairness over time ==")
         for crit in ["drf", "psdsf", "rpsdsf"]:

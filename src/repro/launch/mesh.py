@@ -9,16 +9,27 @@ from __future__ import annotations
 import jax
 
 
+def make_mesh(shape: tuple, axes: tuple):
+    """Device mesh with ``Auto`` axes over the process devices.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which the model
+    stack's sharding constraints and weight gathers no longer type-check;
+    every mesh of the repo is built here with ``Auto`` axes instead."""
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_smoke_mesh():
     """Single-device mesh with the production axis names (CPU tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def make_agent_mesh(n: int):
@@ -40,23 +51,7 @@ def make_agent_mesh(n: int):
 
 
 def make_abstract_mesh(shape: tuple, axes: tuple):
-    """Device-free AbstractMesh across jax API generations.
-
-    The constructor signature has changed across jax releases: some take
-    positional ``(axis_sizes, axis_names)``, others a single ``shape_tuple``
-    of (name, size) pairs.  Each known form is tried in turn; shape/axis
-    resolution (``mesh.shape``) — all the sharding rules consume — is stable
-    across them.
-    """
+    """Device-free mesh of the given shape (sharding-rule resolution only)."""
     from jax.sharding import AbstractMesh
 
-    last_err = None
-    for form in ((tuple(zip(axes, shape)),), (shape, axes)):
-        try:
-            return AbstractMesh(*form)
-        except TypeError as e:
-            last_err = e
-    raise TypeError(
-        f"no known AbstractMesh constructor form matched this jax version "
-        f"(update make_abstract_mesh): {last_err}"
-    )
+    return AbstractMesh(tuple(shape), tuple(axes))

@@ -323,8 +323,9 @@ def test_sharded_wanted_exhaustion_and_limit(pol):
 
 def test_auto_partition_floors_clamp_small_epochs():
     """use_kernel='auto' collapses shards/devices requests below the
-    measured floors to the plain fused dispatch; explicit specs pass
-    through untouched."""
+    measured floors to the plain fused dispatch, and fits a mesh above
+    them to the process's devices; explicit specs pass through untouched."""
+    jax = pytest.importorskip("jax")
     from repro.core.engine import AUTO_MESH_MIN_CELLS, AUTO_SHARD_MIN_CELLS
 
     al = OnlineAllocator(2, criterion="drf", server_policy="pooled", seed=0)
@@ -332,7 +333,8 @@ def test_auto_partition_floors_clamp_small_epochs():
     big_n = AUTO_SHARD_MIN_CELLS // 1024 + 1
     assert al._resolve_partition("auto", big_n, 1024, 8, 1) == (8, 1)
     big_n = AUTO_MESH_MIN_CELLS // 1024 + 1
-    assert al._resolve_partition("auto", big_n, 1024, 1, 8) == (1, 8)
+    assert al._resolve_partition("auto", big_n, 1024, 1, 8) == (
+        1, min(8, len(jax.devices())))
     assert al._resolve_partition("fused", 50, 25, 8, 8) == (8, 8)
     assert al._resolve_partition(True, 50, 25, 4, 2) == (4, 2)
 

@@ -112,6 +112,8 @@ def test_commit_fault_retry_success_bit_identical():
 def test_engine_fault_hook_xla_style_failure_recovers():
     """A raise from inside the engine's dispatch boundary (the chaos hook
     models an XLA/device runtime error) heals like an injected fault."""
+    import jax
+
     from repro.core import engine_jax
 
     baseline = _grant_tuples(
@@ -121,7 +123,7 @@ def test_engine_fault_hook_xla_style_failure_recovers():
     def boom():
         calls["n"] += 1
         if calls["n"] <= 1:
-            raise RuntimeError("XLA: device burst into flames")
+            raise jax.errors.JaxRuntimeError("XLA: device burst into flames")
 
     engine_jax.fault_hook = boom
     try:
@@ -134,6 +136,121 @@ def test_engine_fault_hook_xla_style_failure_recovers():
     assert healed == baseline
     assert calls["n"] >= 2
     assert al.fault_stats.retries >= 1
+
+
+@pytest.mark.parametrize("exc", [NotImplementedError, ValueError, TypeError])
+@pytest.mark.parametrize("where", ["dispatch", "commit"])
+def test_lowering_error_surfaces_instead_of_host_fallback(tmp_path,
+                                                          monkeypatch, exc,
+                                                          where):
+    """An error that is not a device fault (a tracing or lowering error, a
+    refused request) propagates on its first occurrence: no retry, no host
+    fallback, stream rewound and journal bracket closed, so the next epoch
+    is the no-fault epoch and the journal still recovers."""
+    from repro.core import engine_jax
+    from repro.core import journal as J
+
+    baseline = _grant_tuples(
+        build_alloc("rrr").allocate_batched(use_kernel="fused"))
+    al = build_alloc("rrr", recovery=faults.RecoveryPolicy(max_retries=2,
+                                                           backoff_s=0.0))
+    al.journal = J.Journal(str(tmp_path / J.JOURNAL_FILE))
+
+    def boom(*_a, **_k):
+        raise exc("Unimplemented primitive in Pallas TPU lowering")
+
+    if where == "dispatch":
+        monkeypatch.setattr(engine_jax, "fault_hook", boom)
+    else:
+        monkeypatch.setattr(engine_jax.EpochHandle, "result", boom)
+    with pytest.raises(exc, match="lowering"):
+        al.allocate_batched(use_kernel="fused")
+    stats = al.fault_counters()
+    assert stats["host_fallbacks"] == 0 and stats["retries"] == 0
+    assert stats["quarantines"] == 0
+    monkeypatch.undo()
+    assert _grant_tuples(al.allocate_batched(use_kernel="fused")) == baseline
+    al.journal.close()
+    twin = build_alloc("rrr")
+    J.recover(twin, str(tmp_path))
+    assert twin.rng.bit_generator.state == al.rng.bit_generator.state
+    assert {f: fw.n_tasks for f, fw in twin.frameworks.items()} == \
+        {f: fw.n_tasks for f, fw in al.frameworks.items()}
+
+
+def _refuse_compiles_after(monkeypatch, n_ok: int):
+    """Make every compile of an epoch program after the first ``n_ok``
+    fail the way XLA reports a refusal: a JAX runtime error."""
+    import jax
+
+    from repro.core import engine_jax
+
+    monkeypatch.setattr(engine_jax, "_EXECUTABLES", {})
+    compile_ = jax.stages.Lowered.compile
+    calls = {"n": 0}
+
+    def refusing(self, *a, **k):
+        calls["n"] += 1
+        if calls["n"] > n_ok:
+            raise jax.errors.JaxRuntimeError(
+                "RESOURCE_EXHAUSTED: program needs more HBM than the chip")
+        return compile_(self, *a, **k)
+
+    monkeypatch.setattr(jax.stages.Lowered, "compile", refusing)
+
+
+def test_compiler_refusal_is_not_a_device_fault(monkeypatch):
+    """XLA reports a compile that cannot fit as a JAX runtime error, the
+    same type as a device fault.  The engine compiles ahead of the launch,
+    so the refusal surfaces as an EpochCompileError: no retry, no host
+    fallback, and the next epoch is the no-fault epoch."""
+    from repro.core import engine_jax
+
+    baseline = _grant_tuples(
+        build_alloc("pooled").allocate_batched(use_kernel="fused"))
+    al = build_alloc("pooled", recovery=faults.RecoveryPolicy(
+        max_retries=2, backoff_s=0.0))
+    _refuse_compiles_after(monkeypatch, 0)
+    with pytest.raises(engine_jax.EpochCompileError, match="HBM"):
+        al.allocate_batched(use_kernel="fused")
+    stats = al.fault_counters()
+    assert stats["host_fallbacks"] == 0 and stats["retries"] == 0
+    assert stats["dispatch_failures"] == 0
+    assert not faults.is_device_fault(engine_jax.EpochCompileError("x"))
+    monkeypatch.undo()
+    assert _grant_tuples(al.allocate_batched(use_kernel="fused")) == baseline
+
+
+def test_compiler_refusal_at_a_replay_dispatch_surfaces_at_commit(
+        monkeypatch):
+    """A grow-and-replay round compiles a new permutation-stack shape at
+    ``result()``; a refusal there surfaces too, not as a device fault."""
+    from repro.core import engine_jax
+
+    rng = np.random.default_rng(0)
+    N, J = 6, 4
+    kw = dict(X=np.zeros((N, J)), D=np.full((N, 2), 1.0),
+              C=np.full((J, 2), 8.0), FREE=np.full((J, 2), 8.0),
+              phi=np.ones(N), allowed=np.ones((N, J), bool),
+              wanted=np.full(N, 8.0), true_demands=np.full((N, 2), 1.0),
+              rng=rng, _perm_rows=1)
+    _refuse_compiles_after(monkeypatch, 1)
+    handle = engine_jax.run_epoch_async("drf", "rrr", **kw)
+    with pytest.raises(engine_jax.EpochCompileError):
+        handle.result()
+
+
+def test_mesh_request_beyond_the_process_is_refused_unless_auto():
+    """An explicit mesh larger than the process raises; ``auto`` fits it."""
+    import jax
+
+    too_many = len(jax.devices()) + 1
+    al = build_alloc("pooled")
+    with pytest.raises(ValueError, match="device mesh"):
+        al.allocate_batched(use_kernel="fused", devices=too_many)
+    assert al.fault_counters()["host_fallbacks"] == 0
+    assert al._resolve_partition("auto", 2048, 1024, 1, too_many) == (
+        1, len(jax.devices()))
 
 
 def test_fault_free_injector_is_a_noop():

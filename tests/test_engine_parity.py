@@ -323,6 +323,39 @@ def test_batched_epoch_respects_per_agent_limit():
     assert per_agent and all(v == 1 for v in per_agent.values())
 
 
+@pytest.mark.parametrize("crit", ["psdsf", "rpsdsf"])
+@pytest.mark.parametrize("mode", ["characterized", "oblivious"])
+def test_row_minima_pooled_select_equals_full_scan(monkeypatch, crit, mode):
+    """The numpy epoch keeps per-row minima for the pooled select of a
+    server-specific criterion; it must pick exactly what PooledPolicy's
+    full N x J scan picks, on the serving roster, where hundreds of
+    identical agents tie on every row (and, oblivious, where inferred
+    demands move every score)."""
+    from repro.core import engine
+    from repro.launch import alloc_serve
+
+    reqs = alloc_serve.make_profiles(1, 200, seed=3)[0]
+    demand = {r.fid: np.asarray(r.demand) for r in reqs}
+    types = alloc_serve._AGENT_TYPES
+
+    def run():
+        al = OnlineAllocator(2, criterion=crit, server_policy="pooled",
+                             mode=mode, seed=0)
+        al.framework_demand_oracle = demand.__getitem__
+        for j in range(1000 if mode == "characterized" else 400):
+            al.add_agent(f"a{j}", types[j % len(types)])
+        for r in reqs:
+            al.register(r.fid, wanted_tasks=r.n_executors, phi=r.phi,
+                        demand=r.demand if mode == "characterized" else None)
+        return [(g.fid, g.agent, int(g.n_executors))
+                for g in al.allocate_batched(use_kernel=False)]
+
+    fast = run()
+    monkeypatch.setattr(engine, "_RowMinima", lambda n_rows: None)
+    full = run()
+    assert len(full) > 200 and fast == full
+
+
 def test_batched_oblivious_epoch_consistent():
     """Oblivious batched epochs stay capacity-consistent and coarse-grained."""
     al = OnlineAllocator(2, criterion="rpsdsf", server_policy="rrr",

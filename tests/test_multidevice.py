@@ -17,12 +17,13 @@ _SCRIPT = textwrap.dedent("""
 
     from repro.configs import get_config
     from repro.distributed.sharding import make_rules, use_mesh_rules
+    from repro.launch.mesh import make_mesh
     from repro.models.common import get_family
     from repro.nn.param import init_params
     from repro.train.steps import TrainConfig, init_state, make_train_step
 
     assert len(jax.devices()) == 8, jax.devices()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     rules = make_rules()
 
     cfg = get_config("{arch}", smoke=True)
