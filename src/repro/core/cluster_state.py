@@ -36,6 +36,8 @@ from typing import NamedTuple, Optional
 
 import numpy as np
 
+from repro.core import tracing
+
 
 class StateView(NamedTuple):
     """Name-sorted dense view of the active cluster (gathered copies)."""
@@ -343,6 +345,7 @@ class ClusterState:
             Xr=self.Xr[np.ix_(f_slots, a_slots)],
         )
 
+    @tracing.traced("state.epoch_view")
     def epoch_view(self) -> StateView:
         """Frozen :meth:`sorted_view` — the double-buffer an in-flight
         allocation epoch reads from.  The arrays are the same gathered

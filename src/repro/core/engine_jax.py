@@ -117,7 +117,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import criteria
+from repro.core import criteria, tracing
+
+tracing.watch_compiles()
 
 # plain python scalars: this module may be imported lazily while another
 # jit trace is active, so module level must not create jax values.
@@ -856,6 +858,13 @@ def _bucket(n: int, lo: int = 8) -> int:
     return next_pow2(n, lo)
 
 
+def _put(a, dtype=None):
+    """``a`` on the device at ``dtype``, its bytes counted as uploaded."""
+    out = jnp.asarray(a, dtype)
+    tracing.count("engine_jax.upload_bytes", out.nbytes)
+    return out
+
+
 def _pad(a, n, axis, value):
     pad = n - a.shape[axis]
     if pad <= 0:
@@ -946,10 +955,15 @@ class _EpochRun:
             # grow-and-replay instead of a host snapshot.
             self._last_inputs = (X_cur, FREE_cur, used_cur)
         dD, dTD, dC, dphi, dwanted, dallowed = self.consts
+        perms = jnp.asarray(self.perms)
+        scalars = (np.int32(self.pidx), np.int32(self.pos),
+                   jnp.int32(self.J), self.limit, jnp.float32(self.eps))
+        # the state and the constants are on the device already; the
+        # permutation stack and the scalars go up with every dispatch
+        tracing.count("engine_jax.upload_bytes",
+                      perms.nbytes + sum(a.nbytes for a in scalars))
         args = (X_cur, dD, dTD, dC, FREE_cur, dphi, dwanted, dallowed,
-                jnp.asarray(self.perms), used_cur,
-                np.int32(self.pidx), np.int32(self.pos),
-                jnp.int32(self.J), self.limit, jnp.float32(self.eps))
+                perms, used_cur) + scalars
         static = dict(kind=self.kind, policy=self.policy,
                       lookahead=self.lookahead, use_limit=self.use_limit,
                       max_steps=self.max_steps)
@@ -960,11 +974,17 @@ class _EpochRun:
                           interpret=self.interpret, shards=self.shards)
         self.pending = _executable(self.fn, args, static)(*args)
 
+    def _wait(self):
+        """The pending dispatch's outputs, once the device has run it."""
+        with tracing.span("engine_jax.wait"):
+            self.pending[2].block_until_ready()
+        return self.pending
+
     def _finish(self) -> list[tuple[int, int]]:
         out: list[tuple[int, int]] = []
         while True:
             ns, js, count, Xd, _totd, FREEd, usedd, pidx_d, pos_d = \
-                self.pending
+                self._wait()
             if self.policy == "rrr":
                 # a clamped permutation read implies the final cursor ran
                 # past the stack (every used row index is <= the final
@@ -976,29 +996,34 @@ class _EpochRun:
                 while int(pidx_d) >= self.perms.shape[0]:
                     self.perms = np.concatenate(
                         [self.perms, self.draw(self.perms.shape[0])])
-                    if self.donate:
-                        Xs, FREEs, useds = self.snap
-                        self.dispatch(jnp.asarray(Xs, jnp.float32),
-                                      jnp.asarray(FREEs, jnp.float32),
-                                      jnp.asarray(useds, jnp.int32))
-                    else:
-                        self.dispatch(*self._last_inputs)
+                    with tracing.span("engine_jax.upload"):
+                        if self.donate:
+                            Xs, FREEs, useds = self.snap
+                            self.dispatch(_put(Xs, jnp.float32),
+                                          _put(FREEs, jnp.float32),
+                                          _put(useds, jnp.int32))
+                        else:
+                            self.dispatch(*self._last_inputs)
                     ns, js, count, Xd, _totd, FREEd, usedd, pidx_d, pos_d = \
-                        self.pending
-            k = int(count)
-            out.extend(zip(np.asarray(ns[:k]).tolist(),
-                           np.asarray(js[:k]).tolist()))
-            if k < self.max_steps or self.remaining - k <= 0:
-                return out
-            # overflow: chain another dispatch from the final DEVICE state
-            # (incl. the RRR cursor, so the chain equals one long epoch)
-            self.remaining -= k
-            self.pidx, self.pos = int(pidx_d), int(pos_d)
-            if self.policy == "rrr" and self.donate:
-                # snapshot BEFORE the arrays are donated into the next call
-                self.snap = (np.asarray(Xd), np.asarray(FREEd),
-                             np.asarray(usedd))
-            self.dispatch(Xd, FREEd, usedd)
+                        self._wait()
+            with tracing.span("engine_jax.readback"):
+                k = int(count)
+                out.extend(zip(np.asarray(ns[:k]).tolist(),
+                               np.asarray(js[:k]).tolist()))
+                if k < self.max_steps or self.remaining - k <= 0:
+                    return out
+                # overflow: chain another dispatch from the final DEVICE
+                # state (incl. the RRR cursor, so the chain equals one long
+                # epoch)
+                self.remaining -= k
+                self.pidx, self.pos = int(pidx_d), int(pos_d)
+                if self.policy == "rrr" and self.donate:
+                    # snapshot BEFORE the arrays are donated into the next
+                    # call
+                    self.snap = (np.asarray(Xd), np.asarray(FREEd),
+                                 np.asarray(usedd))
+            with tracing.span("engine_jax.upload"):
+                self.dispatch(Xd, FREEd, usedd)
 
 
 class EpochHandle:
@@ -1023,6 +1048,7 @@ class EpochHandle:
         """True until ``result()`` has been driven to completion."""
         return self._seq is None
 
+    @tracing.traced("engine_jax.result")
     def result(self) -> list[tuple[int, int]]:
         if self._seq is None:
             self._seq = self._run._finish()
@@ -1105,74 +1131,76 @@ def run_epoch_async(criterion, policy: str, *, X, D, C, FREE, phi, allowed,
     bound = grant_bound(TD, FREE, tot, wanted, per_agent_limit)
     if bound == 0:
         return EpochHandle(seq=[])
-    Np, Jp = _bucket(N), _bucket(J)
-    limit = np.int32(per_agent_limit if per_agent_limit is not None else 0)
-    use_limit = per_agent_limit is not None
-    shards = max(1, int(shards))
-    shards = 1 << (shards.bit_length() - 1)      # floor to a power of two
-    shards = min(shards, Np, Jp)                 # pow2s: divides both
-    devices = min(devices, Jp)                   # pow2s: divides Jp
+    # staging: pad, cast and upload the epoch's inputs, then launch
+    with tracing.span("engine_jax.upload"):
+        Np, Jp = _bucket(N), _bucket(J)
+        limit = np.int32(0 if per_agent_limit is None else per_agent_limit)
+        use_limit = per_agent_limit is not None
+        shards = max(1, int(shards))
+        shards = 1 << (shards.bit_length() - 1)      # floor to a power of two
+        shards = min(shards, Np, Jp)                 # pow2s: divides both
+        devices = min(devices, Jp)                   # pow2s: divides Jp
 
-    Xp = _pad(_pad(X, Np, 0, 0.0), Jp, 1, 0.0)
-    Dp = _pad(D, Np, 0, 0.0)
-    TDp = _pad(TD, Np, 0, 0.0)
-    Cp = _pad(C, Jp, 0, 0.0)
-    FREEp = _pad(FREE, Jp, 0, 0.0)
-    phip = _pad(phi, Np, 0, 1.0)
-    wantedp = _pad(wanted, Np, 0, 0.0)       # padded frameworks want nothing
-    allowedp = _pad(_pad(allowed, Np, 0, False), Jp, 1, False)
-    usedp = np.zeros(Jp, np.int32)
+        Xp = _pad(_pad(X, Np, 0, 0.0), Jp, 1, 0.0)
+        Dp = _pad(D, Np, 0, 0.0)
+        TDp = _pad(TD, Np, 0, 0.0)
+        Cp = _pad(C, Jp, 0, 0.0)
+        FREEp = _pad(FREE, Jp, 0, 0.0)
+        phip = _pad(phi, Np, 0, 1.0)
+        wantedp = _pad(wanted, Np, 0, 0.0)   # padded frameworks want nothing
+        allowedp = _pad(_pad(allowed, Np, 0, False), Jp, 1, False)
+        usedp = np.zeros(Jp, np.int32)
 
-    def _draw_perms(k: int) -> np.ndarray:
-        """k permutation rows from the shared rng stream, padded to Jp."""
-        rows = np.empty((k, Jp), np.int32)
-        for i in range(k):
-            rows[i, :J] = rng.permutation(J)
-            rows[i, J:] = np.arange(J, Jp)
-        return rows
+        def _draw_perms(k: int) -> np.ndarray:
+            """k permutation rows from the shared rng stream, padded to Jp."""
+            rows = np.empty((k, Jp), np.int32)
+            for i in range(k):
+                rows[i, :J] = rng.permutation(J)
+                rows[i, J:] = np.arange(J, Jp)
+            return rows
 
-    if policy == "rrr":
-        if rng is None:
-            raise ValueError("fused RRR epoch needs the allocator rng")
-        # optimistic budget: one permutation per round of ~J grants plus
-        # wrap slack, sized for one dispatch segment (the stack persists
-        # across chained segments and grows on demand).  The worst case is
-        # 2 per grant (every grant at the round's last position after a
-        # wrap), so if the loop reports its cursor ran PAST the stack we
-        # APPEND more rows — drawing more continues the rng stream, the
-        # already-drawn prefix is unchanged — and re-run the dispatch.
-        # pow2-bucket the stack height so growing `bound` within a bucket
-        # cannot retrace the loop (perms shape is part of the jit key);
-        # _perm_rows is a test hook that forces the grow-and-replay path.
-        if preperms is not None:
-            pp = np.asarray(preperms, np.int32)
-            perms = np.empty((pp.shape[0], Jp), np.int32)
-            perms[:, :J] = pp[:, :J]
-            perms[:, J:] = np.arange(J, Jp)
+        if policy == "rrr":
+            if rng is None:
+                raise ValueError("fused RRR epoch needs the allocator rng")
+            # optimistic budget: one permutation per round of ~J grants
+            # plus wrap slack, sized for one dispatch segment (the stack
+            # persists across chained segments and grows on demand).  The
+            # worst case is 2 per grant (every grant at the round's last
+            # position after a wrap), so if the loop reports its cursor ran
+            # PAST the stack we APPEND more rows — drawing more continues
+            # the rng stream, the already-drawn prefix is unchanged — and
+            # re-run the dispatch.  pow2-bucket the stack height so growing
+            # `bound` within a bucket cannot retrace the loop (perms shape
+            # is part of the jit key); _perm_rows is a test hook that
+            # forces the grow-and-replay path.
+            if preperms is not None:
+                pp = np.asarray(preperms, np.int32)
+                perms = np.empty((pp.shape[0], Jp), np.int32)
+                perms[:, :J] = pp[:, :J]
+                perms[:, J:] = np.arange(J, Jp)
+            else:
+                perms = _draw_perms(_perm_rows if _perm_rows is not None
+                                    else rrr_perm_budget(bound, J,
+                                                         max_steps_cap))
         else:
-            perms = _draw_perms(_perm_rows if _perm_rows is not None
-                                else rrr_perm_budget(bound, J,
-                                                     max_steps_cap))
-    else:
-        perms = np.arange(Jp, dtype=np.int32)[None, :]
+            perms = np.arange(Jp, dtype=np.int32)[None, :]
 
-    fn = _jitted_mesh() if devices > 1 else _jitted(donate)
-    f32 = jnp.float32
-    # constant inputs upload once; the mutable state arrays stay on device
-    # across chained segments (only the grant sequence is read back).
-    consts = (jnp.asarray(Dp, f32), jnp.asarray(TDp, f32),
-              jnp.asarray(Cp, f32), jnp.asarray(phip, f32),
-              jnp.asarray(wantedp, f32), jnp.asarray(allowedp))
-    run = _EpochRun(
-        fn=fn, kind=kind, policy=policy, lookahead=lookahead,
-        use_limit=use_limit, use_pallas=use_pallas, interpret=interpret,
-        shards=shards, devices=devices, J=J, limit=limit, eps=eps,
-        draw=_draw_perms, consts=consts, perms=perms, bound=bound,
-        max_steps_cap=max_steps_cap, donate=donate,
-        snap=(Xp, FREEp, usedp) if policy == "rrr" and donate else None,
-    )
-    run.dispatch(jnp.asarray(Xp, f32), jnp.asarray(FREEp, f32),
-                 jnp.asarray(usedp))
+        fn = _jitted_mesh() if devices > 1 else _jitted(donate)
+        f32 = jnp.float32
+        # constant inputs upload once; the mutable state arrays stay on
+        # device across chained segments (only the grant sequence is read
+        # back).
+        consts = (_put(Dp, f32), _put(TDp, f32), _put(Cp, f32),
+                  _put(phip, f32), _put(wantedp, f32), _put(allowedp))
+        run = _EpochRun(
+            fn=fn, kind=kind, policy=policy, lookahead=lookahead,
+            use_limit=use_limit, use_pallas=use_pallas, interpret=interpret,
+            shards=shards, devices=devices, J=J, limit=limit, eps=eps,
+            draw=_draw_perms, consts=consts, perms=perms, bound=bound,
+            max_steps_cap=max_steps_cap, donate=donate,
+            snap=(Xp, FREEp, usedp) if policy == "rrr" and donate else None,
+        )
+        run.dispatch(_put(Xp, f32), _put(FREEp, f32), _put(usedp))
     return EpochHandle(run=run)
 
 

@@ -87,6 +87,8 @@ from typing import NamedTuple, Optional
 
 import numpy as np
 
+from repro.core import tracing
+
 #: default LRU byte budget (~32 MiB holds ~10^5 hundred-grant outcomes)
 DEFAULT_MAX_BYTES = 32 << 20
 
@@ -196,6 +198,7 @@ class EpochCache:
     # -- fingerprint ---------------------------------------------------------
 
     @staticmethod
+    @tracing.traced("epoch_cache.fingerprint")
     def fingerprint(view, TD, *, criterion: str, policy: str, mode: str,
                     tie: str, engine: str,
                     per_agent_limit: Optional[int] = None,

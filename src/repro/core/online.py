@@ -81,6 +81,7 @@ from repro.core import invariants as _invariants
 from repro.core import journal as _journal
 from repro.core import preemption as _preemption
 from repro.core import tenancy as _tenancy
+from repro.core import tracing as _tracing
 from repro.core.cluster_state import ClusterState, StateView
 from repro.core.engine import (
     AUTO_KERNEL_FLOOR_CELLS,
@@ -1121,6 +1122,7 @@ class OnlineAllocator:
             devices = min(devices, len(jax.devices()))
         return shards, devices
 
+    @_tracing.traced("online.begin_epoch")
     def begin_epoch(self, per_agent_limit: Optional[int] = None,
                     tie: str = "low", use_kernel="auto",
                     shards: int = 1, devices: int = 1) -> InFlightEpoch:
@@ -1271,6 +1273,7 @@ class OnlineAllocator:
                              guard=self.state.mutation_count,
                              revocations=revs)
 
+    @_tracing.traced("online.commit_epoch")
     def commit_epoch(self, epoch: InFlightEpoch) -> list[Grant]:
         """Commit an in-flight epoch: block on the device grant sequence,
         re-validate each grant in f64 against the LIVE state and apply it

@@ -9,8 +9,9 @@ cache (:mod:`repro.core.epoch_cache`): steady-state traffic repeats a small
 set of (demands, capacities, weights) profiles, so after the first
 occurrence of each profile every epoch is a cache hit — a fingerprint lookup
 plus a grant replay instead of a device dispatch.  The driver reports
-served-decisions/sec, decision-latency p50/p99
-(:class:`~repro.core.metrics.LatencyStats`) and the cache counters.
+served-decisions/sec, the seconds each epoch took (p50/p99 of its
+``service.drain_epoch`` span, :mod:`repro.core.tracing`) and the cache
+counters.
 
     PYTHONPATH=src python -m repro.launch.alloc_serve --smoke \
         --out SERVE_cache_stats.json
@@ -25,6 +26,7 @@ asserts; the CI chaos job runs it and archives the recovery stats).
 from __future__ import annotations
 
 import argparse
+import collections
 import json
 import os
 import time
@@ -36,6 +38,7 @@ from repro.core import faults as _faults
 from repro.core import invariants as _invariants
 from repro.core import journal as _journal
 from repro.core import metrics as _metrics
+from repro.core import tracing as _tracing
 from repro.core.online import OnlineAllocator
 from repro.launch import compile_cache
 
@@ -124,7 +127,10 @@ class AllocatorService:
         self.max_queue = max_queue
         self.max_retries = int(max_retries)
         self.backoff_s = float(backoff_s)
-        self.latency = _metrics.LatencyStats()
+        # seconds of each drain_epoch call, the interval its
+        # service.drain_epoch span covers (kept with the recorder off too);
+        # the percentiles read the last 65,536
+        self.epoch_s: collections.deque = collections.deque(maxlen=1 << 16)
         self.decisions = 0
         self.epochs = 0
         self.rejected_backpressure = 0
@@ -166,7 +172,15 @@ class AllocatorService:
         raise last
 
     def drain_epoch(self) -> list:
-        """Apply queued requests, run one (cached) epoch, return grants."""
+        """Apply queued requests, run one (cached) epoch, return grants.
+        The call is the root span of the epoch (``service.drain_epoch``)."""
+        t0 = time.perf_counter()
+        with _tracing.span("service.drain_epoch", epoch=self.epochs):
+            grants = self._drain()
+        self.epoch_s.append(time.perf_counter() - t0)
+        return grants
+
+    def _drain(self) -> list:
         now = self.clock()
         live = []
         for req in self._queue:
@@ -197,10 +211,7 @@ class AllocatorService:
                 self.alloc.set_wanted(
                     req.fid, fw.wanted_tasks + req.n_executors)
         self._queue.clear()
-        t0 = time.perf_counter()
         grants = self._run_epoch_with_retry()
-        dt = time.perf_counter() - t0
-        self.latency.record(dt, max(len(grants), 1))
         self.decisions += len(grants)
         self.epochs += 1
         if (self.state_dir is not None
@@ -228,6 +239,7 @@ class AllocatorService:
             self.alloc.journal.close()
             self.alloc.journal = None
 
+    @_tracing.traced("service.complete")
     def complete(self, fid: str) -> None:
         """A framework finished: release its executors and deregister —
         freed capacity re-enters the pool, the profile can recur."""
@@ -257,6 +269,12 @@ class AllocatorService:
             "journal_lag_fsync": 0,
             "journal_lag_snapshot": 0,
         }
+        # process-wide: every allocator in the process adds to these
+        tr = _tracing.totals()
+        out["upload_bytes"] = tr.get("engine_jax.upload_bytes", 0)
+        out["lowerings"] = tr.get("jax.lowerings", 0)
+        out["compile_s"] = tr.get("jax.compile_s", 0.0)
+        out["trace_dropped"] = tr["dropped"]
         if self.alloc.tenancy is not None:
             out["admissions"] = self.alloc.tenancy.counters()
         if self.alloc.journal is not None:
@@ -285,12 +303,21 @@ class AllocatorService:
             out["admissions"] = self.alloc.tenancy.counters()
         return out
 
+    def _epoch_summary(self) -> dict:
+        """Count, p50 and p99 of the seconds each epoch took."""
+        out = {"count": self.epochs, "p50": 0.0, "p99": 0.0}
+        if self.epoch_s:
+            s = np.asarray(self.epoch_s)
+            out["p50"] = float(np.percentile(s, 50))
+            out["p99"] = float(np.percentile(s, 99))
+        return out
+
     def stats(self) -> dict:
         cache = self.alloc.epoch_cache
         out = {
             "epochs": self.epochs,
             "decisions": self.decisions,
-            "latency": self.latency.summary(),
+            "epoch_s": self._epoch_summary(),
             "cache": cache.stats() if cache is not None else None,
             "health": self.health(),
         }
