@@ -16,9 +16,29 @@ Fingerprint
 :meth:`EpochCache.fingerprint` hashes (blake2b) a canonical byte encoding of
 every input the epoch outcome depends on:
 
-  * the frozen view arrays — ``D, C, X, Xr, FREE, phi, allowed, wanted`` —
-    plus the true-demand matrix ``TD``, each tagged and length/shape-prefixed
-    so fields can never run into each other;
+  * the frozen view arrays ``D, C, FREE, phi, wanted`` plus the true-demand
+    matrix ``TD``, dense, each tagged and length/shape-prefixed so fields
+    can never run into each other;
+  * the three (N, J) view arrays by their content, not their shape (a
+    churn epoch holds ~10^4 executors in 7·10^6 cells):
+
+    - ``X``: its shape, the flat indices of its non-zeros (int32 while
+      N·J < 2^31, int64 beyond, the index dtype in the field's meta), then
+      the values there.  A non-zero is what ``np.flatnonzero`` finds, so a
+      ``-0.0`` count encodes as zero;
+    - ``Xr``: its values at X's support, once ``count_nonzero`` shows it
+      has no non-zero off that support (``Xr ≤ X`` holds by construction,
+      but the key never rests on it: an Xr that breaks it gets its own
+      indices and values under another tag).  ``Xr=None`` encodes as an
+      all-zero Xr;
+    - ``allowed``: ``np.packbits`` of the mask, one bit per cell.
+
+    Where the sparse bytes of X (index + X value + Xr value per non-zero)
+    reach the dense bytes of X and Xr, both are hashed dense, each under a
+    tag of its own; likewise an off-support Xr whose own indices and
+    values reach its dense bytes.  The choice is a pure function of the
+    content, so equal content gives an equal key, and no field's two
+    encodings can ever feed blake2b the same bytes;
   * the configuration — criterion, server policy, mode, tie rule, engine
     path (host / host-pergrant / fused), ``per_agent_limit``, the best-fit
     metric, and the preemption config (threshold, eps);
@@ -28,6 +48,12 @@ every input the epoch outcome depends on:
     pure function of the profile) is drawn BEFORE lookup and hashed into
     the key — two epochs with equal profiles but different rng streams can
     never share an entry.
+
+Each call counts the bytes it hands to blake2b (``epoch_cache.hashed_bytes``)
+and the (N, J) fields it hashed dense (``epoch_cache.dense_fields``) on its
+``epoch_cache.fingerprint`` span.  The meta string leads with the key
+version (``epoch-v2``), so entries of older spill files still load but can
+never answer a current key.
 
 The view arrays are *name-sorted* (``epoch_view``), so fingerprints are
 independent of registration / dict-process order by construction: clusters
@@ -160,11 +186,27 @@ def verify_seq(outcome: EpochOutcome) -> bool:
     return seq_digest_of(outcome.seq) == outcome.seq_digest
 
 
-def _hash_field(h, tag: bytes, payload: bytes) -> None:
-    """Tag + length-prefix every field so encodings can never collide
-    across field boundaries (b'ab'+b'c' vs b'a'+b'bc')."""
-    h.update(tag)
-    h.update(len(payload).to_bytes(8, "little"))
+class _Hasher:
+    """blake2b that counts the bytes it is handed."""
+
+    def __init__(self):
+        self._h = hashlib.blake2b(digest_size=_DIGEST_SIZE)
+        self.nbytes = 0
+
+    def update(self, data) -> None:
+        self._h.update(data)
+        self.nbytes += memoryview(data).nbytes
+
+    def digest(self) -> bytes:
+        return self._h.digest()
+
+
+def _hash_field(h, tag: bytes, payload) -> None:
+    """Length-prefix the tag and the payload of every field so encodings
+    can never collide across field boundaries (b'ab'+b'c' vs b'a'+b'bc').
+    ``payload`` is bytes or a C-contiguous array, hashed in place."""
+    h.update(len(tag).to_bytes(1, "little") + tag)
+    h.update(memoryview(payload).nbytes.to_bytes(8, "little"))
     h.update(payload)
 
 
@@ -172,7 +214,72 @@ def _hash_array(h, tag: bytes, arr: np.ndarray) -> None:
     a = np.ascontiguousarray(arr)
     meta = f"{a.dtype.str}{a.shape}".encode()
     _hash_field(h, tag + b"#", meta)
-    _hash_field(h, tag, a.tobytes())
+    _hash_field(h, tag, a)
+
+
+def _hash_values(h, tag: bytes, arr: np.ndarray, vals: np.ndarray) -> None:
+    """``arr`` by ``vals``, its values on a support hashed beside them."""
+    _hash_field(h, tag + b"#", f"{arr.dtype.str}{arr.shape}".encode())
+    _hash_field(h, tag, vals)
+
+
+def _index_dtype(arr: np.ndarray) -> np.dtype:
+    """int32 flat indices while they fit, int64 beyond."""
+    return np.dtype(np.int32 if arr.size < 1 << 31 else np.int64)
+
+
+def _hash_sparse(h, tag: bytes, arr: np.ndarray, nz: np.ndarray) -> None:
+    """``arr`` by the flat indices ``nz`` of its non-zeros and the values
+    there, the index dtype in the meta."""
+    idx = nz.astype(_index_dtype(arr))
+    _hash_field(h, tag + b".idx#", f"{arr.shape}{idx.dtype.str}".encode())
+    _hash_field(h, tag + b".idx", idx)
+    _hash_values(h, tag + b".val", arr, np.ravel(arr)[nz])
+
+
+def _nonzeros(arr: np.ndarray) -> np.ndarray:
+    """``np.flatnonzero(arr)``, through a boolean mask: the same cells
+    (``!= 0``, so -0.0 is zero and NaN is not), several times faster on
+    floats."""
+    return np.flatnonzero(arr != 0)
+
+
+def _sparse_nbytes(arr: np.ndarray, nnz: int, *values: np.dtype) -> int:
+    return nnz * (_index_dtype(arr).itemsize
+                  + sum(v.itemsize for v in values))
+
+
+def _hash_placement(h, X: np.ndarray, Xr: Optional[np.ndarray]) -> int:
+    """X and Xr by their content (module doc); returns how many of the
+    two were hashed dense.  ``Xr=None`` hashes as zeros of X's dtype."""
+    xr_dtype = X.dtype if Xr is None else Xr.dtype
+    nz = _nonzeros(X)
+    if (_sparse_nbytes(X, nz.size, X.dtype, xr_dtype)
+            >= X.size * (X.itemsize + xr_dtype.itemsize)):
+        _hash_array(h, b"X", X)
+        _hash_array(h, b"Xr", np.zeros(X.shape, X.dtype) if Xr is None
+                    else Xr)
+        return 2
+    _hash_sparse(h, b"X.sparse", X, nz)
+    if Xr is None:
+        _hash_values(h, b"Xr.onX", X, np.zeros(nz.size, X.dtype))
+        return 0
+    on = np.ravel(Xr)[nz]
+    if np.count_nonzero(Xr != 0) == np.count_nonzero(on):
+        _hash_values(h, b"Xr.onX", Xr, on)
+        return 0
+    nzr = _nonzeros(Xr)
+    if _sparse_nbytes(Xr, nzr.size, Xr.dtype) >= Xr.nbytes:
+        _hash_array(h, b"Xr", Xr)
+        return 1
+    _hash_sparse(h, b"Xr.sparse", Xr, nzr)
+    return 0
+
+
+def _hash_bits(h, tag: bytes, mask: np.ndarray) -> None:
+    """A boolean mask at one bit per cell (``np.packbits``, C order)."""
+    _hash_field(h, tag + b"#", f"{mask.dtype.str}{mask.shape}".encode())
+    _hash_field(h, tag, np.packbits(mask))
 
 
 class EpochCache:
@@ -214,24 +321,24 @@ class EpochCache:
         documented f32/tile tie-semantics boundaries); ``preemption`` is
         ``(threshold, eps)`` or None; ``perms`` the dispatch-time RRR
         permutation prefix (fused RRR only)."""
-        h = hashlib.blake2b(digest_size=_DIGEST_SIZE)
+        h = _Hasher()
         meta = "|".join((
-            "epoch-v1", criterion, policy, mode, tie, engine,
+            "epoch-v2", criterion, policy, mode, tie, engine,
             repr(per_agent_limit), repr(bf_metric), repr(preemption),
         )).encode()
         _hash_field(h, b"meta", meta)
-        _hash_array(h, b"X", view.X)
-        _hash_array(h, b"Xr", view.Xr if view.Xr is not None
-                    else np.zeros_like(view.X))
+        dense = _hash_placement(h, view.X, view.Xr)
         _hash_array(h, b"D", view.D)
         _hash_array(h, b"C", view.C)
         _hash_array(h, b"FREE", view.FREE)
         _hash_array(h, b"phi", view.phi)
-        _hash_array(h, b"allowed", view.allowed)
+        _hash_bits(h, b"allowed.bits", view.allowed)
         _hash_array(h, b"wanted", view.wanted)
         _hash_array(h, b"TD", np.asarray(TD))
         if perms is not None:
             _hash_array(h, b"perms", np.asarray(perms, np.int64))
+        tracing.count("epoch_cache.hashed_bytes", h.nbytes)
+        tracing.count("epoch_cache.dense_fields", dense)
         return h.digest()
 
     # -- LRU -----------------------------------------------------------------

@@ -32,6 +32,7 @@ from repro.core.epoch_cache import (
     get_cache,
     perm_digest,
 )
+from repro.core.cluster_state import StateView
 from repro.core.online import OnlineAllocator
 from repro.core.preemption import PreemptionPolicy
 
@@ -216,6 +217,169 @@ def test_order_independent_rebuild_hits():
                 fw_order=[4, 0, 2, 1, 3]).allocate_batched(use_kernel="fused")
     assert cache.hits == 1 and cache.misses == 1
     assert _gkey(g1) == _gkey(g2)
+
+
+# ---------------------------------------------------------------------------
+# the (N, J) fields by content: X / Xr support and the packed allowed mask,
+# on both sides of the dense switch
+# ---------------------------------------------------------------------------
+
+_KW = dict(criterion="drf", policy="rrr", mode="m", tie="low",
+           engine="fused")
+
+
+def _view(N=6, J=10, fill=0.2, seed=0):
+    """A frozen view whose X holds executors in ``fill`` of its cells
+    (0.2: sparse side of the switch; 0.9: dense side), Xr a subset of X."""
+    rng = np.random.default_rng(seed)
+    cells = rng.permutation(N * J)[:round(fill * N * J)]
+    X = np.zeros(N * J)
+    X[cells] = rng.integers(1, 4, cells.size)
+    X = X.reshape(N, J)
+    Xr = np.where(rng.random((N, J)) < 0.5, X, 0.0)
+    return StateView(tuple(range(N)), tuple(range(J)), X,
+                     rng.random((N, 2)), rng.random((J, 2)),
+                     rng.random((J, 2)), np.ones(N), rng.random((N, J)) < 0.7,
+                     np.full(N, 6.0), Xr)
+
+
+def _copy(v, **fields):
+    for k in ("X", "Xr", "allowed"):
+        if k not in fields and getattr(v, k) is not None:
+            fields[k] = getattr(v, k).copy()
+    return v._replace(**fields)
+
+
+def _cell(v, nonzero):
+    """The first cell of X that is (or is not) non-zero."""
+    n, j = np.argwhere((v.X != 0) == nonzero)[0]
+    return n, j
+
+
+def _flip_x_on(v):
+    w = _copy(v)
+    w.X[_cell(v, False)] = 2.0
+    return v, w, False
+
+
+def _flip_x_off(v):
+    w = _copy(v)
+    c = _cell(v, True)
+    w.X[c] = w.Xr[c] = 0.0
+    return v, w, False
+
+
+def _move_executor(v):
+    w = _copy(v)
+    a, b = _cell(v, True), _cell(v, False)
+    w.X[b], w.X[a], w.Xr[a] = w.X[a], 0.0, 0.0
+    assert np.count_nonzero(w.X) == np.count_nonzero(v.X)
+    return v, w, False
+
+
+def _xr_on_support(v):
+    w = _copy(v)
+    c = _cell(v, True)
+    w.Xr[c] = 0.0 if v.Xr[c] else v.X[c]
+    return v, w, False
+
+
+def _xr_off_support(v):
+    w = _copy(v)
+    w.Xr[_cell(v, False)] = 1.0
+    return v, w, False
+
+
+def _allowed_bit(v):
+    w = _copy(v)
+    w.allowed[2, 3] = not w.allowed[2, 3]
+    return v, w, False
+
+
+def _xr_none(v):
+    return _copy(v, Xr=np.zeros_like(v.X)), _copy(v, Xr=None), True
+
+
+def _equal_copy(v):
+    return v, _copy(v), True
+
+
+_VIEW_FLIPS = {
+    "x_cell_zero_to_value": _flip_x_on,
+    "x_cell_value_to_zero": _flip_x_off,
+    "x_executor_moved": _move_executor,
+    "xr_on_x_support": _xr_on_support,
+    "xr_off_x_support": _xr_off_support,
+    "allowed_bit": _allowed_bit,
+    "xr_none_is_all_zero": _xr_none,
+    "equal_copy": _equal_copy,
+}
+
+
+def _tags(monkeypatch, v):
+    """The field tags ``fingerprint(v)`` hashes, in order."""
+    from repro.core import epoch_cache
+
+    tags, real = [], epoch_cache._hash_field
+    monkeypatch.setattr(
+        epoch_cache, "_hash_field",
+        lambda h, tag, payload: (tags.append(tag), real(h, tag, payload)))
+    EpochCache.fingerprint(v, v.D, **_KW)
+    monkeypatch.setattr(epoch_cache, "_hash_field", real)
+    return tags
+
+
+@pytest.mark.parametrize("fill", (0.2, 0.9), ids=("sparse", "dense"))
+@pytest.mark.parametrize("flip", sorted(_VIEW_FLIPS))
+def test_view_perturbation(monkeypatch, flip, fill):
+    """Each single change to X, Xr or allowed misses; equal content hits,
+    whichever side of the dense switch the view lies on."""
+    a, b, hit = _VIEW_FLIPS[flip](_view(fill=fill))
+    ka = EpochCache.fingerprint(a, a.D, **_KW)
+    kb = EpochCache.fingerprint(b, b.D, **_KW)
+    assert (ka == kb) is hit
+    tags = _tags(monkeypatch, b)
+    assert (b"X.sparse.idx" in tags) is (fill < 0.8)
+    assert (b"X" in tags) is (fill >= 0.8)
+    if fill < 0.8:
+        off = flip == "xr_off_x_support"
+        assert (b"Xr.sparse.idx" in tags) is off
+        assert (b"Xr.onX" in tags) is not off
+
+
+def test_view_key_equality_is_field_equality():
+    """Over ~200 small random views (both sides of the switch, Xr on, off
+    and without X's support, and None), two keys are equal exactly where
+    X, Xr (None as zeros) and allowed are equal."""
+    rng = np.random.default_rng(7)
+    base = _view(2, 3)
+    views = []
+    for _ in range(200):
+        shape = ((2, 3), (3, 2))[rng.integers(2)]
+        X = rng.integers(1, 3, shape) * (rng.random(shape) < rng.choice(
+            (0.2, 0.5, 1.0))) + 0.0
+        kind = rng.integers(4)
+        Xr = (None if kind == 0
+              else np.zeros(shape) if kind == 1
+              else X * (rng.random(shape) < 0.5) if kind == 2
+              else (rng.random(shape) < 0.3).astype(float))
+        allowed = rng.random(shape) < 0.8
+        views.append(base._replace(X=X, Xr=Xr,
+                                   allowed=allowed))
+    keys = [EpochCache.fingerprint(v, base.D, **_KW) for v in views]
+
+    def same(v, w):
+        zr = lambda u: np.zeros_like(u.X) if u.Xr is None else u.Xr
+        return (v.X.shape == w.X.shape and np.array_equal(v.X, w.X)
+                and np.array_equal(zr(v), zr(w))
+                and np.array_equal(v.allowed, w.allowed))
+
+    equal = 0
+    for i, v in enumerate(views):
+        for j, w in enumerate(views[:i]):
+            assert (keys[i] == keys[j]) is same(v, w), (i, j)
+            equal += keys[i] == keys[j]
+    assert equal > 0
 
 
 # ---------------------------------------------------------------------------

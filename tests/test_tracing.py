@@ -277,3 +277,54 @@ def test_program_spans_appear_on_the_profiler_host_plane(recorder,
 
 def test_span_names_cover_every_span_the_epoch_records():
     assert set(EPOCH_TREE) | {"service.complete"} == set(tracing.SPAN_NAMES)
+
+
+def _placement_view(N, J, dense, seed=0):
+    """A view whose X holds ~5 executors per row (``dense=False``, as a
+    churn epoch does) or one in every cell (``dense=True``)."""
+    from repro.core.cluster_state import StateView
+
+    rng = np.random.default_rng(seed)
+    X = np.zeros((N, J))
+    if dense:
+        X[:] = rng.integers(1, 4, (N, J))
+    else:
+        for n in range(N):
+            X[n, rng.choice(J, 5, replace=False)] = rng.integers(1, 4, 5)
+    Xr = np.where(rng.random((N, J)) < 0.3, X, 0.0)
+    return StateView(tuple(range(N)), tuple(range(J)), X,
+                     rng.random((N, 2)), rng.random((J, 2)),
+                     rng.random((J, 2)), np.ones(N), rng.random((N, J)) < 0.9,
+                     np.full(N, 6.0), Xr)
+
+
+@pytest.mark.parametrize("shape,dense", [((1800, 4034), False),
+                                         ((64, 48), True)])
+def test_the_fingerprint_counts_its_bytes_and_dense_fields(recorder, shape,
+                                                           dense):
+    from repro.core.epoch_cache import EpochCache
+
+    view = _placement_view(*shape, dense)
+    EpochCache.fingerprint(view, view.D, criterion="drf", policy="rrr",
+                           mode="m", tie="low", engine="fused")
+    (fp,) = tracing.records()
+    assert fp.name == "epoch_cache.fingerprint"
+    hashed = fp.attrs["epoch_cache.hashed_bytes"]
+    assert hashed == tracing.totals()["epoch_cache.hashed_bytes"]
+    cells = view.X.nbytes + view.Xr.nbytes + view.allowed.nbytes
+    if dense:
+        assert fp.attrs["epoch_cache.dense_fields"] >= 1
+        assert hashed > view.X.nbytes + view.Xr.nbytes
+    else:
+        assert fp.attrs["epoch_cache.dense_fields"] == 0
+        assert hashed < 0.05 * cells
+
+
+def test_service_counters_report_the_fingerprint_counters(recorder):
+    service = _service("rrr", "drf")
+    _epoch(service)
+    (fp,) = [r for r in tracing.records()
+             if r.name == "epoch_cache.fingerprint"]
+    c = service.counters()
+    assert c["hashed_bytes"] == fp.attrs["epoch_cache.hashed_bytes"] > 0
+    assert c["dense_fields"] == fp.attrs["epoch_cache.dense_fields"]
