@@ -11,7 +11,9 @@ Contracts pinned here:
     any single input field (one demand element, one phi, one allowed bit,
     TD/wanted, criterion, policy, per_agent_limit, preemption threshold,
     RRR perm prefix) MISSES, while process-order-independent rebuilds of
-    the same profile HIT;
+    the same profile HIT; view by view, each change to an X cell, Xr (on
+    or off X's support) or an allowed bit MISSES and equal content (Xr=None
+    as all-zero) HITS, on both sides of the dense switch;
   * eligibility gates — host RRR, oblivious mode and non-"low" ties bypass
     the cache entirely (no lookups, no stores, no rng perturbation);
   * commit semantics — cached fused epochs keep the ``mutation_count``
@@ -26,13 +28,13 @@ import numpy as np
 import pytest
 
 from repro.core import engine_jax
+from repro.core.cluster_state import StateView
 from repro.core.epoch_cache import (
     EpochCache,
     EpochOutcome,
     get_cache,
     perm_digest,
 )
-from repro.core.cluster_state import StateView
 from repro.core.online import OnlineAllocator
 from repro.core.preemption import PreemptionPolicy
 
@@ -252,8 +254,7 @@ def _copy(v, **fields):
 
 def _cell(v, nonzero):
     """The first cell of X that is (or is not) non-zero."""
-    n, j = np.argwhere((v.X != 0) == nonzero)[0]
-    return n, j
+    return tuple(np.argwhere((v.X != 0) == nonzero)[0])
 
 
 def _flip_x_on(v):
