@@ -22,23 +22,12 @@ every input the epoch outcome depends on:
   * the three (N, J) view arrays by their content, not their shape (a
     churn epoch holds ~10^4 executors in 7·10^6 cells):
 
-    - ``X``: its shape, the flat indices of its non-zeros (int32 while
-      N·J < 2^31, int64 beyond, the index dtype in the field's meta), then
-      the values there.  A non-zero is what ``np.flatnonzero`` finds, so a
-      ``-0.0`` count encodes as zero;
-    - ``Xr``: its values at X's support, once ``count_nonzero`` shows it
-      has no non-zero off that support (``Xr ≤ X`` holds by construction,
-      but the key never rests on it: an Xr that breaks it gets its own
-      indices and values under another tag).  ``Xr=None`` encodes as an
-      all-zero Xr;
-    - ``allowed``: ``np.packbits`` of the mask, one bit per cell.
-
-    Where the sparse bytes of X (index + X value + Xr value per non-zero)
-    reach the dense bytes of X and Xr, both are hashed dense, each under a
-    tag of its own; likewise an off-support Xr whose own indices and
-    values reach its dense bytes.  The choice is a pure function of the
-    content, so equal content gives an equal key, and no field's two
-    encodings can ever feed blake2b the same bytes;
+    - ``X`` and ``Xr``, each on its own: its shape, the flat indices of
+      its non-zeros (int32 while N·J < 2^31, int64 beyond, the index dtype
+      in the field's meta), then the values there.  A non-zero is what
+      ``np.flatnonzero`` finds, so a ``-0.0`` count encodes as zero.
+      ``Xr=None`` encodes as an all-zero Xr of X's shape and dtype;
+    - ``allowed``: ``np.packbits`` of the mask, one bit per cell;
   * the configuration — criterion, server policy, mode, tie rule, engine
     path (host / host-pergrant / fused), ``per_agent_limit``, the best-fit
     metric, and the preemption config (threshold, eps);
@@ -50,8 +39,7 @@ every input the epoch outcome depends on:
     never share an entry.
 
 Each call counts the bytes it hands to blake2b (``epoch_cache.hashed_bytes``)
-and the (N, J) fields it hashed dense (``epoch_cache.dense_fields``) on its
-``epoch_cache.fingerprint`` span.  The meta string leads with the key
+on its ``epoch_cache.fingerprint`` span.  The meta string leads with the key
 version (``epoch-v2``), so entries of older spill files still load but can
 never answer a current key.
 
@@ -217,24 +205,19 @@ def _hash_array(h, tag: bytes, arr: np.ndarray) -> None:
     _hash_field(h, tag, a)
 
 
-def _hash_values(h, tag: bytes, arr: np.ndarray, vals: np.ndarray) -> None:
-    """``arr`` by ``vals``, its values on a support hashed beside them."""
-    _hash_field(h, tag + b"#", f"{arr.dtype.str}{arr.shape}".encode())
-    _hash_field(h, tag, vals)
-
-
 def _index_dtype(arr: np.ndarray) -> np.dtype:
     """int32 flat indices while they fit, int64 beyond."""
     return np.dtype(np.int32 if arr.size < 1 << 31 else np.int64)
 
 
 def _hash_sparse(h, tag: bytes, arr: np.ndarray, nz: np.ndarray) -> None:
-    """``arr`` by the flat indices ``nz`` of its non-zeros and the values
-    there, the index dtype in the meta."""
+    """``arr`` by its dtype and shape, the flat indices ``nz`` of its
+    non-zeros and the values there, the index dtype in the meta."""
     idx = nz.astype(_index_dtype(arr))
-    _hash_field(h, tag + b".idx#", f"{arr.shape}{idx.dtype.str}".encode())
+    _hash_field(h, tag + b"#",
+                f"{arr.dtype.str}{arr.shape}{idx.dtype.str}".encode())
     _hash_field(h, tag + b".idx", idx)
-    _hash_values(h, tag + b".val", arr, np.ravel(arr)[nz])
+    _hash_field(h, tag + b".val", np.ravel(arr)[nz])
 
 
 def _nonzeros(arr: np.ndarray) -> np.ndarray:
@@ -244,36 +227,14 @@ def _nonzeros(arr: np.ndarray) -> np.ndarray:
     return np.flatnonzero(arr != 0)
 
 
-def _sparse_nbytes(arr: np.ndarray, nnz: int, *values: np.dtype) -> int:
-    return nnz * (_index_dtype(arr).itemsize
-                  + sum(v.itemsize for v in values))
-
-
-def _hash_placement(h, X: np.ndarray, Xr: Optional[np.ndarray]) -> int:
-    """X and Xr by their content (module doc); returns how many of the
-    two were hashed dense.  ``Xr=None`` hashes as zeros of X's dtype."""
-    xr_dtype = X.dtype if Xr is None else Xr.dtype
-    nz = _nonzeros(X)
-    if (_sparse_nbytes(X, nz.size, X.dtype, xr_dtype)
-            >= X.size * (X.itemsize + xr_dtype.itemsize)):
-        _hash_array(h, b"X", X)
-        _hash_array(h, b"Xr", np.zeros(X.shape, X.dtype) if Xr is None
-                    else Xr)
-        return 2
-    _hash_sparse(h, b"X.sparse", X, nz)
+def _hash_placement(h, X: np.ndarray, Xr: Optional[np.ndarray]) -> None:
+    """X and Xr, each by its own non-zeros (module doc); ``Xr=None`` as an
+    all-zero Xr of X's shape and dtype: no support."""
+    _hash_sparse(h, b"X", X, _nonzeros(X))
     if Xr is None:
-        _hash_values(h, b"Xr.onX", X, np.zeros(nz.size, X.dtype))
-        return 0
-    on = np.ravel(Xr)[nz]
-    if np.count_nonzero(Xr != 0) == np.count_nonzero(on):
-        _hash_values(h, b"Xr.onX", Xr, on)
-        return 0
-    nzr = _nonzeros(Xr)
-    if _sparse_nbytes(Xr, nzr.size, Xr.dtype) >= Xr.nbytes:
-        _hash_array(h, b"Xr", Xr)
-        return 1
-    _hash_sparse(h, b"Xr.sparse", Xr, nzr)
-    return 0
+        _hash_sparse(h, b"Xr", X, np.empty(0, np.intp))
+    else:
+        _hash_sparse(h, b"Xr", Xr, _nonzeros(Xr))
 
 
 def _hash_bits(h, tag: bytes, mask: np.ndarray) -> None:
@@ -327,7 +288,7 @@ class EpochCache:
             repr(per_agent_limit), repr(bf_metric), repr(preemption),
         )).encode()
         _hash_field(h, b"meta", meta)
-        dense = _hash_placement(h, view.X, view.Xr)
+        _hash_placement(h, view.X, view.Xr)
         _hash_array(h, b"D", view.D)
         _hash_array(h, b"C", view.C)
         _hash_array(h, b"FREE", view.FREE)
@@ -338,7 +299,6 @@ class EpochCache:
         if perms is not None:
             _hash_array(h, b"perms", np.asarray(perms, np.int64))
         tracing.count("epoch_cache.hashed_bytes", h.nbytes)
-        tracing.count("epoch_cache.dense_fields", dense)
         return h.digest()
 
     # -- LRU -----------------------------------------------------------------

@@ -273,7 +273,6 @@ class AllocatorService:
         tr = _tracing.totals()
         out["upload_bytes"] = tr.get("engine_jax.upload_bytes", 0)
         out["hashed_bytes"] = tr.get("epoch_cache.hashed_bytes", 0)
-        out["dense_fields"] = tr.get("epoch_cache.dense_fields", 0)
         out["lowerings"] = tr.get("jax.lowerings", 0)
         out["compile_s"] = tr.get("jax.compile_s", 0.0)
         out["trace_dropped"] = tr["dropped"]
