@@ -104,21 +104,30 @@ def _dominant_occupancy(service, agents) -> float:
     return float(((cap - free) / cap).max())
 
 
+#: the keyword arguments of ``AllocatorService`` that the harness sets; a
+#: configuration's ``service`` object may give any other
+SERVICE_SET = ("n_resources", "agents", "criterion", "server_policy",
+               "use_kernel", "epoch_cache", "seed")
+
+
 def new_service(cell, agents, seed: int):
+    """The service of the cell's configuration: the :data:`SERVICE_SET`
+    keys, then the settings of its ``service`` object."""
     from repro.launch import alloc_serve
 
     cfg = cell.config
     return alloc_serve.AllocatorService(
         len(cfg["resources"]), agents, criterion=cfg["criterion"],
         server_policy=cfg["server_policy"], use_kernel=cfg["use_kernel"],
-        epoch_cache=cfg["epoch_cache"], seed=seed)
+        epoch_cache=cfg["epoch_cache"], seed=seed, **cfg.get("service", {}))
 
 
 def _submit(service, log, req):
     from repro.launch.alloc_serve import AllocRequest
 
-    service.submit(AllocRequest(req.fid, req.demand, req.n_executors))
-    log.register(req.fid, req.demand, req.n_executors)
+    service.submit(AllocRequest(req.fid, req.demand, req.n_executors,
+                                phi=req.phi))
+    log.register(req.fid, req.demand, req.n_executors, req.phi)
 
 
 def _drain(service, log, checked: bool):
@@ -176,7 +185,8 @@ def set_up(cell, seed: int, seconds: float):
         frameworks, places = traffic.standing(mix, cfg, agents, seed)
     elif mix["loop"] == "poisson":
         steady, places = traffic.steady(mix, cfg, agents, seed)
-        frameworks = [(r.fid, r.demand, r.n_executors) for r, _ in steady]
+        frameworks = [(r.fid, r.demand, r.n_executors, r.phi)
+                      for r, _ in steady]
         plan["steady"] = steady
         plan["arrivals"] = traffic.arrivals(mix, cfg, seconds, seed)
     else:
@@ -184,11 +194,12 @@ def set_up(cell, seed: int, seconds: float):
     by_fid: dict = {}
     for p in places:
         by_fid.setdefault(p[0], []).append(p)
-    for fid, demand, wanted in frameworks:
-        service.alloc.register(fid, demand=demand, wanted_tasks=wanted)
+    for fid, demand, wanted, phi in frameworks:
+        service.alloc.register(fid, demand=demand, wanted_tasks=wanted,
+                               phi=phi)
         for f, agent, n in by_fid[fid]:
             service.alloc.force_place(f, agent, n)
-        log.place(fid, demand, wanted, by_fid[fid])
+        log.place(fid, demand, wanted, phi, by_fid[fid])
     plan["occupancy"] = _dominant_occupancy(service, agents)
     return service, log, plan
 
@@ -246,11 +257,11 @@ def warm_up(cell, service, log, plan, seed) -> int:
         reqs = traffic.batch(mix, cell.config, seed, -1)
         for req in reqs:
             service.alloc.register(req.fid, demand=req.demand,
-                                   wanted_tasks=req.n_executors)
+                                   wanted_tasks=req.n_executors, phi=req.phi)
         for req in reqs:
             service.complete(req.fid)
         return 0
-    reqs = [traffic.Request("w" + r.fid, r.demand, r.n_executors)
+    reqs = [traffic.Request("w" + r.fid, r.demand, r.n_executors, r.phi)
             for _, r, _ in plan["arrivals"][:int(mix["rate_rps"])]]
     for req in reqs:
         _submit(service, log, req)

@@ -1,10 +1,11 @@
 """The benchmark's own record of what it asked for and what it was granted,
 and the comparison that decides ``correct``.
 
-During the run the harness logs every registration, every epoch's committed
-grants (with the allocator's rng state at the epoch's start, the one input
-of an RRR epoch that is not cluster state) and every completion.  After the
-window closes, :func:`check` replays that log against the plain reference:
+During the run the harness logs every registration with its weight, every
+epoch's committed grants (with the allocator's rng state at the epoch's
+start, the one input of an RRR epoch that is not cluster state) and every
+completion.  After the window closes, :func:`check` replays that log against
+the configuration's plain reference (``bench.spec.reference``):
 each epoch's committed sequence must equal the reference's on the same
 inputs, no committed grant may oversubscribe a machine or go to a framework
 that did not ask, and the program's free resources at the end must equal
@@ -24,13 +25,14 @@ class Log:
     agents: list                       # [(name, capacity)], roster order
     events: list = dataclasses.field(default_factory=list)
 
-    def place(self, fid, demand, wanted, places):
+    def place(self, fid, demand, wanted, phi, places):
         """A framework present before the window, with its placements."""
         self.events.append(("place", fid, tuple(demand), int(wanted),
-                            tuple(places)))
+                            float(phi), tuple(places)))
 
-    def register(self, fid, demand, wanted):
-        self.events.append(("register", fid, tuple(demand), int(wanted)))
+    def register(self, fid, demand, wanted, phi):
+        self.events.append(("register", fid, tuple(demand), int(wanted),
+                            float(phi)))
 
     def epoch(self, rng_state, grants, checked: bool):
         self.events.append(("epoch", rng_state, tuple(grants), checked))
@@ -43,6 +45,7 @@ class Log:
 class _Fw:
     demand: np.ndarray
     wanted: int
+    phi: float
     held: dict                          # agent index -> executors
 
 
@@ -72,7 +75,8 @@ class Replay:
 
     def inputs(self):
         """Rows (wanting frameworks in name order) and columns (machines in
-        name order) of the next epoch."""
+        name order) of the next epoch: ``rows, D, tot, wanted, phi,
+        free``."""
         rows = sorted(f for f, fw in self.fws.items()
                       if sum(fw.held.values()) < fw.wanted)
         D = np.asarray([self.fws[f].demand for f in rows]).reshape(
@@ -80,7 +84,8 @@ class Replay:
         tot = np.asarray([sum(self.fws[f].held.values()) for f in rows],
                          np.float64)
         wanted = np.asarray([self.fws[f].wanted for f in rows], np.float64)
-        return rows, D, tot, wanted, self.free[self.order]
+        phi = np.asarray([self.fws[f].phi for f in rows], np.float64)
+        return rows, D, tot, wanted, phi, self.free[self.order]
 
 
 #: the controls a configuration can name: each breaks one stated guarantee
@@ -88,11 +93,12 @@ class Replay:
 CONTROLS = ("bfloat16_scores",)
 
 
-def check(log: Log, config: dict, program_free: dict, *,
+def check(log: Log, config: dict, epoch, program_free: dict, *,
           control=None) -> dict:
-    """Replay ``log`` against the reference; the numbers compared, each
-    ``{"value": v, "limit": 0}``.  ``program_free`` maps agent -> the
-    program's free vector after the run.
+    """Replay ``log`` against the reference ``epoch`` of ``config`` (a
+    cell's ``reference``); the numbers compared, each ``{"value": v,
+    "limit": 0}``.  ``program_free`` maps agent -> the program's free
+    vector after the run.
 
     ``control`` puts a broken reference in the program's place and
     compares it with the true one: ``bfloat16_scores`` holds every score
@@ -103,33 +109,33 @@ def check(log: Log, config: dict, program_free: dict, *,
                    else None)
     rep = Replay(log.agents)
     ctot = rep.cap.sum(axis=0)
-    crit, pol = config["criterion"], config["server_policy"]
     compared = mismatched = unrequested = 0
     first_diff = None
     for ev in log.events:
         kind = ev[0]
         if kind == "place":
-            _, fid, demand, wanted, places = ev
-            rep.fws[fid] = _Fw(np.asarray(demand, np.float64), wanted, {})
+            _, fid, demand, wanted, phi, places = ev
+            rep.fws[fid] = _Fw(np.asarray(demand, np.float64), wanted, phi,
+                               {})
             for f, agent, n in places:
                 rep.grant(f, agent, n)
         elif kind == "register":
-            _, fid, demand, wanted = ev
-            rep.fws[fid] = _Fw(np.asarray(demand, np.float64), wanted, {})
+            _, fid, demand, wanted, phi = ev
+            rep.fws[fid] = _Fw(np.asarray(demand, np.float64), wanted, phi,
+                               {})
         elif kind == "complete":
             rep.complete(ev[1])
         else:
             _, rng_state, grants, checked = ev
             if checked:
-                rows, D, tot, wanted, free = rep.inputs()
+                rows, D, tot, wanted, phi, free = rep.inputs()
                 rng = None
                 if rng_state is not None:
                     rng = np.random.Generator(np.random.PCG64())
                     rng.bit_generator.state = rng_state
-                seq = reference.epoch(
-                    crit, pol, D=D, tot=tot, wanted=wanted,
-                    phi=np.ones(len(rows)), free=free, ctot=ctot, rng=rng,
-                    score_round=score_round)
+                seq = epoch(config, D=D, tot=tot, wanted=wanted, phi=phi,
+                            free=free, ctot=ctot, rng=rng,
+                            score_round=score_round)
                 want = [(rows[n], rep.names[rep.order[j]]) for n, j in seq]
                 compared += 1
                 if want != list(grants):

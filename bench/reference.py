@@ -22,6 +22,20 @@ Semantics of one epoch over the frameworks that still want executors
 * the epoch ends when no pair is feasible.
 
 ``score_round`` rounds every score (the control holds them in bfloat16).
+
+A configuration whose criterion and server policy this file does not cover
+brings its own reference as ``bench/references/<criterion>-<server_policy>.py``
+(found by ``bench.spec.reference``; such a file also wins over this one).
+It defines
+
+    epoch(config, *, D, tot, wanted, phi, free, ctot, rng, score_round)
+        -> [(row, column), ...]
+
+with the inputs of :func:`epoch` below, as float64 numpy arrays, and
+``config`` the configuration file as loaded (for settings such as a
+best-fit metric).  It imports nothing of ``repro``, and passes every score
+through ``score_round`` when that is given, so that the control still
+breaks it.  :func:`config_epoch` is this file's epoch in that form.
 """
 from __future__ import annotations
 
@@ -55,6 +69,16 @@ def _dominant(D: np.ndarray, cap: np.ndarray) -> np.ndarray:
     return out
 
 
+#: the (criterion, server policy) pairs :func:`epoch` covers
+COVERED = (("rpsdsf", "pooled"), ("drf", "rrr"), ("rpsdsf", "rrr"))
+
+
+def config_epoch(config: dict, **inputs) -> list:
+    """:func:`epoch` for ``config``'s criterion and server policy, in the
+    form of a reference file."""
+    return epoch(config["criterion"], config["server_policy"], **inputs)
+
+
 def epoch(criterion: str, policy: str, *, D, tot, wanted, phi, free,
           ctot=None, rng=None, score_round=None) -> list:
     """The reference grant sequence ``[(row, column), ...]`` of one epoch.
@@ -63,6 +87,8 @@ def epoch(criterion: str, policy: str, *, D, tot, wanted, phi, free,
     executors wanted, ``phi`` (W,) weights, ``free`` (J, R) free resources;
     ``ctot`` (R,) pooled capacity (DRF); ``rng`` a numpy Generator at the
     epoch's stream position (RRR)."""
+    if (criterion, policy) not in COVERED:
+        raise ValueError(f"no reference for {criterion}/{policy}")
     rnd = score_round or (lambda x: x)
     D = np.asarray(D, np.float64)
     state = dict(tot=np.array(tot, np.float64),
@@ -71,13 +97,11 @@ def epoch(criterion: str, policy: str, *, D, tot, wanted, phi, free,
                  free=np.array(free, np.float64))
     if len(D) == 0 or len(state["free"]) == 0:
         return []
-    if criterion == "rpsdsf" and policy == "pooled":
+    if policy == "pooled":
         return _pooled_pairwise(D, rnd, **state)
-    if criterion in ("drf", "rpsdsf") and policy == "rrr":
-        if rng is None:
-            raise ValueError("an rrr epoch needs the allocator's rng state")
-        return _rrr(criterion, D, rnd, ctot=ctot, rng=rng, **state)
-    raise ValueError(f"no reference for {criterion}/{policy}")
+    if rng is None:
+        raise ValueError("an rrr epoch needs the allocator's rng state")
+    return _rrr(criterion, D, rnd, ctot=ctot, rng=rng, **state)
 
 
 def _column(D: np.ndarray, wants, free_j: np.ndarray):

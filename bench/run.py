@@ -5,13 +5,13 @@
 
 Builds the cell's cluster and load from the seed, warms every epoch program
 the window will run, measures the window from the client's side, then
-replays the window's epochs against the plain reference
-(``bench/reference.py``) to decide ``correct``.  With ``--trace 0`` the
-result carries the cell's end-to-end metrics; with ``--trace 1`` a
-profiler trace of the window and spans around the program's layer entry
-points give its per-layer metrics and a breakdown.  The last line of
-standard output is the result object; the numbers compared are the last
-lines of standard error.
+replays the window's epochs against the configuration's plain reference
+(``bench/reference.py``, or its own under ``bench/references/``) to decide
+``correct``.  With ``--trace 0`` the result carries the cell's end-to-end
+metrics; with ``--trace 1`` a profiler trace of the window and spans around
+the program's layer entry points give its per-layer metrics and a
+breakdown.  The last line of standard output is the result object; the
+numbers compared are the last lines of standard error.
 
 A process that finds no TPU, or fewer chips than the cell asks for, exits
 non-zero and prints no result.  ``--rehearse`` runs the cell at the small
@@ -139,10 +139,10 @@ def run_cell(args, *, root: str = ROOT, control: bool = False) -> dict:
     program_free = service.alloc.free
     del service
     t_check = time.perf_counter()
-    verdict = ledger.check(log, cell.config, program_free)
+    verdict = ledger.check(log, cell.config, cell.reference, program_free)
     verdict["check_s"] = time.perf_counter() - t_check
     checks = verdict["checks"]
-    ctl = (ledger.check(log, cell.config, program_free,
+    ctl = (ledger.check(log, cell.config, cell.reference, program_free,
                         control=cell.config["control"]["kind"])
            if control else None)
     correct = (verdict["epochs_compared"] > 0

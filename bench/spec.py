@@ -1,15 +1,24 @@
-"""Find a cell's configuration, traffic mix and metric readers by name.
+"""Find a cell's configuration, traffic mix, reference and metric readers
+by name.
 
-Nothing here names a cell, configuration, mix or metric: a later change adds
-one by adding its files and its entry in ``BENCHMARK.json``.
+Nothing here names a cell, configuration, mix, criterion, server policy or
+metric: a later change adds one by adding its files and its entry in
+``BENCHMARK.json``.  A configuration brings its reference as a file of its
+own (:func:`reference`), its framework weights as a table in its file
+(``bench/traffic.py``), and settings of the service as its ``service``
+object (keyword arguments of ``AllocatorService``).
 """
 from __future__ import annotations
 
 import dataclasses
 import importlib.util
+import inspect
 import json
 import os
 import re
+
+from bench import drive
+from bench import reference as plain
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -27,6 +36,7 @@ class Cell:
     traffic: dict          # the mix, then the cell's own parameters
     end_to_end: list       # metric entries of BENCHMARK.json this cell reports
     per_layer: list
+    reference: object      # the configuration's epoch(config, **inputs)
 
 
 def _read_json(path: str) -> dict:
@@ -66,6 +76,8 @@ def load_cell(name: str, *, root: str = ROOT, rehearse: bool = False) -> Cell:
     conf_entry = configs[w["config"]]
     config = _overlay(_read_json(os.path.join(root, conf_entry["file"])),
                       rehearse)
+    epoch = reference(config, root=root)
+    _check_service(config)
     traffic = _overlay(_read_json(os.path.join(
         root, "bench", "traffic", _checked(w["traffic"]) + ".json")), rehearse)
     cell_file = os.path.join(root, "bench", "cells", _checked(name) + ".json")
@@ -74,7 +86,49 @@ def load_cell(name: str, *, root: str = ROOT, rehearse: bool = False) -> Cell:
     return Cell(
         name=name, chips=int(w["chips"]), config=config, traffic=traffic,
         end_to_end=[m for m in bench["end_to_end"] if _reports(m, name)],
-        per_layer=[m for m in bench["per_layer"] if _reports(m, name)])
+        per_layer=[m for m in bench["per_layer"] if _reports(m, name)],
+        reference=epoch)
+
+
+def _load(path: str, prefix: str, name: str):
+    spec = importlib.util.spec_from_file_location(
+        prefix + re.sub(r"\W", "_", name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reference(config: dict, *, root: str = ROOT):
+    """The ``epoch`` function of the configuration's plain reference:
+    ``bench/references/<criterion>-<server_policy>.py`` where that file
+    exists, else ``bench/reference.py`` for the pairs it covers (its
+    docstring gives a reference file's contract)."""
+    try:
+        pair = (_checked(config["criterion"]),
+                _checked(config["server_policy"]))
+    except KeyError as exc:
+        raise SpecError(f"the configuration names no {exc.args[0]}") from None
+    rel = os.path.join("bench", "references", "-".join(pair) + ".py")
+    path = os.path.join(root, rel)
+    if os.path.exists(path):
+        return _load(path, "bench_reference_", "-".join(pair)).epoch
+    if pair in plain.COVERED:
+        return plain.config_epoch
+    raise SpecError(f"no reference for {'/'.join(pair)}: add {rel}")
+
+
+def _check_service(config: dict) -> None:
+    """Refuse a key of the configuration's ``service`` object that
+    ``AllocatorService`` does not take, or that the harness sets."""
+    if "service" not in config:
+        return
+    from repro.launch.alloc_serve import AllocatorService
+
+    takes = set(inspect.signature(AllocatorService).parameters)
+    bad = sorted(set(config["service"]) - (takes - set(drive.SERVICE_SET)))
+    if bad:
+        raise SpecError(f"AllocatorService takes no service setting "
+                        f"{', '.join(map(repr, bad))} from a configuration")
 
 
 def reader(metric: str, *, root: str = ROOT):
@@ -88,8 +142,4 @@ def reader(metric: str, *, root: str = ROOT):
     if not os.path.exists(path):
         raise SpecError(f"no reader for metric {metric!r} under "
                         f"{os.path.relpath(base, root)}")
-    mod_name = "bench_metric_" + re.sub(r"\W", "_", metric)
-    spec = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(path, "bench_metric_", metric).read

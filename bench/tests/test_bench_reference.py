@@ -75,7 +75,7 @@ def test_the_bfloat16_control_departs_from_the_reference():
         fws, places = traffic.standing(mix, cfg, agents, seed)
         index = {a: j for j, (a, _) in enumerate(agents)}
         free = np.asarray([c for _, c in agents], float)
-        dem = {f: np.asarray(d) for f, d, _ in fws}
+        dem = {f: np.asarray(d) for f, d, _, _ in fws}
         for f, a, n in places:
             free[index[a]] -= n * dem[f]
         batch = traffic.batch(mix, cfg, seed, 0)

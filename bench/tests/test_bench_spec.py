@@ -32,7 +32,8 @@ def test_a_cell_is_discovered_from_added_files(tmp_path):
     }
     _write(tmp_path, "BENCHMARK.json", json.dumps(bench))
     _write(tmp_path, "bench/configs/tiny.json", json.dumps(
-        {"machines": [], "size": 1, "rehearse": {"size": 0}}))
+        {"machines": [], "size": 1, "criterion": "drf",
+         "server_policy": "rrr", "rehearse": {"size": 0}}))
     _write(tmp_path, "bench/traffic/burst.json", json.dumps(
         {"loop": "rounds", "batch": 4, "rehearse": {"batch": 2}}))
     _write(tmp_path, "bench/cells/tiny.burst.json", json.dumps(

@@ -76,11 +76,11 @@ def test_standing_load_fits_every_machine():
     cell = _cell("borg2011-rpsdsf.fill")
     agents = traffic.roster(cell.config, 9)
     fws, places = traffic.standing(cell.traffic, cell.config, agents, 9)
-    dem = {f: np.asarray(d) for f, d, _ in fws}
+    dem = {f: np.asarray(d) for f, d, _, _ in fws}
     cap = dict(agents)
     for fid, agent, n in places:
         assert (n * dem[fid] <= np.asarray(cap[agent])).all()
-    assert sum(n for _, _, n in places) == sum(w for _, _, w in fws)
+    assert sum(n for _, _, n in places) == sum(w for _, _, w, _ in fws)
 
 
 def test_steady_state_fits_the_cluster():

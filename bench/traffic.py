@@ -2,14 +2,17 @@
 
 Every number comes from the configuration file, the mix file (with the
 cell's own parameters over it) and ``--seed``.  The multiset of request
-sizes, demands, gaps and hold times is drawn once from the mix's
+sizes, demands, weights, gaps and hold times is drawn once from the mix's
 ``shape_seed``; ``--seed`` only permutes it and places it.  So every seed
 offers the same work, in another order and on another layout.
 
 Per-executor demands come from the configuration's ``executor_demand``
 table, in its capacity units.  Each value has at most 8 significant bits,
 so it is exact in bfloat16 and float32 (the chip's bf16 products stay
-exact), while scores built from several of them are not.
+exact), while scores built from several of them are not.  Framework
+weights come from its optional ``weights`` table, ``[[phi, p], ...]`` in
+the same form and under the same rule, drawn on streams of their own;
+without one every weight is 1 and nothing is drawn.
 """
 from __future__ import annotations
 
@@ -23,6 +26,7 @@ class Request:
     fid: str
     demand: tuple          # per-executor demand, one entry per resource
     n_executors: int
+    phi: float = 1.0       # the framework's weight
 
 
 def _rng(seed: int, *stream: int) -> np.random.Generator:
@@ -48,18 +52,35 @@ def _bf16_exact(values: np.ndarray) -> bool:
     return bool((np.round(m * 256) == m * 256).all())
 
 
+def _draw(table: list, scale: float, n: int, rng, what: str) -> np.ndarray:
+    """``n`` values of a ``[[value, p], ...]`` table, each times ``scale``."""
+    rows = np.asarray(table, float)
+    values = rows[:, 0] * scale
+    if not _bf16_exact(values):
+        raise ValueError(f"{what} need more than 8 significant bits: "
+                         f"{values.tolist()}")
+    return rng.choice(values, size=n, p=rows[:, 1] / rows[:, 1].sum())
+
+
 def _demands(table: dict, resources: list, scale, n: int, rng) -> np.ndarray:
     """(n, R) demands: per resource, a value of the table's ``[[value, p],
     ...]`` times that resource's ``scale``."""
     out = np.empty((n, len(resources)))
     for r, res in enumerate(resources):
-        rows = np.asarray(table[res], float)
-        values = rows[:, 0] * scale[r]
-        if not _bf16_exact(values):
-            raise ValueError(f"{res} demands need more than 8 significant "
-                             f"bits: {values.tolist()}")
-        out[:, r] = rng.choice(values, size=n, p=rows[:, 1] / rows[:, 1].sum())
+        out[:, r] = _draw(table[res], scale[r], n, rng, f"{res} demands")
     return out
+
+
+def _weights(mix: dict, config: dict, n: int, stream: int) -> np.ndarray:
+    """``n`` framework weights from the configuration's ``weights`` table,
+    on a stream of their own; all 1, with no draw, where it has none."""
+    if "weights" not in config:
+        return np.ones(n)
+    phi = _draw(config["weights"], 1.0, n, _rng(mix["shape_seed"], 9, stream),
+                "weights")
+    if not (phi > 0).all():
+        raise ValueError(f"weights must be positive: {sorted(set(phi))}")
+    return phi
 
 
 def _executors(law: dict, n: int, rng) -> np.ndarray:
@@ -71,18 +92,21 @@ def _executors(law: dict, n: int, rng) -> np.ndarray:
 
 
 def shapes(mix: dict, config: dict, n: int, stream: int):
-    """The fixed multiset of ``n`` request shapes (executors, demands) of
-    this mix: drawn from ``shape_seed`` alone, never from ``--seed``."""
+    """The fixed multiset of ``n`` request shapes (executors, demands,
+    weights) of this mix: drawn from ``shape_seed`` alone, never from
+    ``--seed``."""
     rng = _rng(mix["shape_seed"], stream)
     return (_executors(mix["executors"], n, rng),
             _demands(config["executor_demand"], config["resources"],
-                     np.ones(len(config["resources"])), n, rng))
+                     np.ones(len(config["resources"])), n, rng),
+            _weights(mix, config, n, stream))
 
 
 # -- closed loop: a standing load, then batches in rounds -------------------
 
 def standing(mix: dict, config: dict, agents: list, seed: int):
-    """The standing load: ``[(fid, demand, wanted)], [(fid, agent, n)]``.
+    """The standing load: ``[(fid, demand, wanted, phi)], [(fid, agent,
+    n)]``.
 
     Each machine holds executors of one long-running framework up to a
     share of its capacity drawn from ``occupancy`` ([low, high]); their
@@ -91,6 +115,7 @@ def standing(mix: dict, config: dict, agents: list, seed: int):
     k = int(st["frameworks"])
     dem = _demands(st["demand_fraction"], config["resources"],
                    largest(config), k, _rng(mix["shape_seed"], 7))
+    phi = _weights(mix, config, k, 7)
     rng = _rng(seed, 2)
     owner = rng.integers(0, k, size=len(agents))
     share = rng.uniform(*st["occupancy"], size=len(agents))
@@ -98,8 +123,8 @@ def standing(mix: dict, config: dict, agents: list, seed: int):
     count = np.floor((share[:, None] * caps / dem[owner]).min(axis=1))
     fids = [f"s{i:04d}" for i in range(k)]
     wanted = np.bincount(owner, weights=count, minlength=k)
-    frameworks = [(fids[i], tuple(dem[i]), int(wanted[i])) for i in range(k)
-                  if wanted[i] > 0]
+    frameworks = [(fids[i], tuple(dem[i]), int(wanted[i]), float(phi[i]))
+                  for i in range(k) if wanted[i] > 0]
     places = [(fids[owner[j]], agents[j][0], int(count[j]))
               for j in range(len(agents)) if count[j] > 0]
     return frameworks, places
@@ -108,10 +133,10 @@ def standing(mix: dict, config: dict, agents: list, seed: int):
 def batch(mix: dict, config: dict, seed: int, rnd: int) -> list:
     """Round ``rnd``'s batch: the mix's fixed multiset, permuted by seed."""
     n = int(mix["batch"])
-    execs, dem = shapes(mix, config, n, stream=3)
+    execs, dem, phi = shapes(mix, config, n, stream=3)
     order = _rng(seed, 3, rnd % 100000).permutation(n)
     return [Request(f"r{rnd % 100000:05d}f{i:05d}", tuple(dem[k]),
-                    int(execs[k]))
+                    int(execs[k]), float(phi[k]))
             for i, k in enumerate(order)]
 
 
@@ -132,7 +157,7 @@ def steady(mix: dict, config: dict, agents: list, seed: int):
     of a length-biased draw, as a renewal process seen at a random time.
     Each executor sits on a machine drawn from ``seed`` that has room."""
     n = int(mix["frameworks_steady"])
-    execs, dem = shapes(mix, config, n, stream=4)
+    execs, dem, phi = shapes(mix, config, n, stream=4)
     sigma = float(mix["hold"]["sigma"])
     base = _rng(mix["shape_seed"], 5)
     # length-biased lognormal: the same sigma, the mean times exp(sigma^2)
@@ -143,7 +168,8 @@ def steady(mix: dict, config: dict, agents: list, seed: int):
     free = np.asarray([c for _, c in agents], float)
     out, places = [], []
     for i, k in enumerate(order):
-        req = Request(f"b{i:07d}", tuple(dem[k]), int(execs[k]))
+        req = Request(f"b{i:07d}", tuple(dem[k]), int(execs[k]),
+                      float(phi[k]))
         out.append((req, float(residual[k])))
         counts: dict = {}
         for _ in range(req.n_executors):
@@ -165,7 +191,7 @@ def arrivals(mix: dict, config: dict, seconds: float, seed: int):
     ``rate_rps * seconds`` requests whose gaps are a fixed multiset of
     exponential gaps scaled to span the window, permuted by ``seed``."""
     n = max(1, int(round(float(mix["rate_rps"]) * seconds)))
-    execs, dem = shapes(mix, config, n, stream=6)
+    execs, dem, phi = shapes(mix, config, n, stream=6)
     base = _rng(mix["shape_seed"], 8)
     gaps = base.exponential(size=n)
     holds = _lognormal(hold_mean(mix), float(mix["hold"]["sigma"]), n, base)
@@ -174,5 +200,6 @@ def arrivals(mix: dict, config: dict, seconds: float, seed: int):
     due = np.cumsum(gaps) / gaps.sum() * seconds * n / (n + 1)
     order = rng.permutation(n)
     return [(float(due[i]),
-             Request(f"a{i:07d}", tuple(dem[order[i]]), int(execs[order[i]])),
+             Request(f"a{i:07d}", tuple(dem[order[i]]), int(execs[order[i]]),
+                     float(phi[order[i]])),
              float(holds[order[i]])) for i in range(n)]
