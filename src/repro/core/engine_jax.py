@@ -15,8 +15,31 @@ sequence ``(n_k, j_k)`` comes back in a single transfer when the loop ends.
 Coverage: characterized mode, ``tie="low"``, every criterion (DRF / TSF /
 PS-DSF / rPS-DSF) under the ``pooled`` and ``rrr`` server policies —
 including phi != 1 priorities, placement constraints, ``per_agent_limit``
-and mid-epoch exhaustion of ``wanted``.  Oblivious mode (inferred-demand
-drift) and best-fit stay on the host paths.
+and mid-epoch exhaustion of ``wanted`` — and the global criteria (DRF,
+TSF) under ``bestfit`` with the ``cosine`` metric (BF-DRF).  Oblivious
+mode (inferred-demand drift), the other best-fit metrics and best-fit
+after a server-specific criterion stay on the host paths; best-fit is
+refused on the device mesh, with ``shards > 1`` and with ``use_pallas``.
+
+Best-fit on device
+------------------
+The framework is the pooled global select's (masked 1-D argmin of the
+criterion over the rows with any feasible column).  The server is the
+feasible column of that row whose free vector ``a`` (the loop's own
+``FREE``) best matches the row's demand ``d``: the least ``1 - cos(a, d)``,
+i.e. the greatest ``(a.d)**2 / (a.a)``.  f32 cannot order these: on the
+Borg cell's integer units distinct scores lie as close as 1.9e-10, inside
+the f32 tie band, and the TPU's f32 divide and sqrt are not correctly
+rounded.  So the key is compared exactly, in int32, by cross
+multiplication — ``u1**2 * v2`` against ``u2**2 * v1`` with ``u = a.d`` and
+``v = a.a``, each product held in three 15-bit limbs — inside one variadic
+reduction that breaks equal keys (collinear free vectors) toward the
+lowest index, as the float64 host policy does.  It needs integer ``FREE``
+and demands with ``u < 2**15`` and ``v < 2**30``; the host checks that
+before the dispatch and raises where it does not hold
+(:func:`check_bestfit_inputs`).  On the Borg grid (every executor demand
+against every fitting free vector in [0, 256]^2) the exact order is the
+float64 order (``tests/test_engine_parity.py``).
 
 Randomized round-robin on device
 --------------------------------
@@ -143,17 +166,123 @@ DISPATCH_COUNT = 0
 fault_hook = None
 
 COVERED_CRITERIA = ("drf", "tsf", "psdsf", "rpsdsf")
-COVERED_POLICIES = ("pooled", "rrr")
+COVERED_POLICIES = ("pooled", "rrr", "bestfit")
+#: best-fit on device: the criteria that score a framework, not a pair, and
+#: the one metric whose order the exact key reproduces
+BESTFIT_CRITERIA = ("drf", "tsf")
+BESTFIT_METRICS = ("cosine",)
+#: the exact best-fit key's bounds (exclusive) on ``u = a.d`` and
+#: ``v = a.a``: ``u**2`` and ``v`` below 2**30 keep every limb product and
+#: carry of :func:`_wide_mul` inside int32
+BESTFIT_DOT_LIMIT = 2**15
+BESTFIT_NORM_LIMIT = 2**30
 
 
-def supports(criterion, policy: str, mode: str, tie: str) -> bool:
+def supports(criterion, policy: str, mode: str, tie: str, *,
+             bf_metric: str = "cosine", shards: int = 1, devices: int = 1,
+             use_pallas=False) -> bool:
     """Can the fused device epoch serve this configuration?"""
     try:
         name = criteria.get_criterion(criterion).name
     except ValueError:
         return False
+    if policy == "bestfit" and (
+            name not in BESTFIT_CRITERIA or bf_metric not in BESTFIT_METRICS
+            or shards > 1 or devices > 1 or use_pallas):
+        return False
     return (name in COVERED_CRITERIA and policy in COVERED_POLICIES
             and mode == "characterized" and tie == "low")
+
+
+def check_bestfit_inputs(FREE, TD, wants, allowed, eps: float = 1e-9) -> None:
+    """Refuse a best-fit epoch the exact device key cannot order: free
+    vectors or demands that are not whole numbers, negative demands, or
+    sizes past :data:`BESTFIT_DOT_LIMIT` / :data:`BESTFIT_NORM_LIMIT`.
+    Only what the key can meet is checked: the demands of the rows that
+    want executors, and the free vectors of the columns one of them may use
+    and fits at the epoch's start (free resources only fall within an
+    epoch, so no other column ever becomes feasible)."""
+    wants = np.asarray(wants, bool)
+    if not wants.any():
+        return
+    TD = np.asarray(TD, np.float64)[wants]
+    FREE = np.asarray(FREE, np.float64)
+    cols = (np.asarray(allowed, bool)[wants].any(axis=0)
+            & (FREE + eps >= TD.min(axis=0)).all(axis=1))
+    FREE = FREE[cols]
+    if not (np.array_equal(FREE, np.round(FREE))
+            and np.array_equal(TD, np.round(TD))):
+        raise ValueError(
+            "best-fit on the device needs free resources and demands in "
+            "whole units: its exact key compares integers (give the "
+            "capacities in units that make them whole, or run the epoch "
+            "on the host)")
+    if (TD < 0).any():
+        raise ValueError("best-fit on the device needs demands >= 0")
+    if not len(FREE):
+        return
+    dot = float(np.abs(FREE).max(axis=0) @ TD.max(axis=0))
+    norm = float((FREE * FREE).sum(axis=1).max())
+    if dot >= BESTFIT_DOT_LIMIT or norm >= BESTFIT_NORM_LIMIT:
+        raise ValueError(
+            f"best-fit on the device orders free.demand < "
+            f"{BESTFIT_DOT_LIMIT} and free.free < {BESTFIT_NORM_LIMIT} "
+            f"exactly; this epoch reaches {dot:.0f} and {norm:.0f}")
+
+
+_LIMB = 15
+_LIMB_MASK = (1 << _LIMB) - 1
+
+
+def _wide_mul(p, q):
+    """``p * q`` for int32 ``p, q`` in [0, 2**30), exactly, as three 15-bit
+    limbs ``(hi, mid, lo)``: every partial product and carry stays below
+    2**31."""
+    ph, pl = p >> _LIMB, p & _LIMB_MASK
+    qh, ql = q >> _LIMB, q & _LIMB_MASK
+    lo = pl * ql
+    mid = ph * ql + pl * qh + (lo >> _LIMB)
+    hi = ph * qh + (mid >> _LIMB)
+    return hi, mid & _LIMB_MASK, lo & _LIMB_MASK
+
+
+def _wide_gt(a, b):
+    return (a[0] > b[0]) | ((a[0] == b[0]) & (
+        (a[1] > b[1]) | ((a[1] == b[1]) & (a[2] > b[2]))))
+
+
+def _wide_eq(a, b):
+    return (a[0] == b[0]) & (a[1] == b[1]) & (a[2] == b[2])
+
+
+def bestfit_keys(free, d):
+    """``(u**2, max(v, 1))`` per column, ``u = free.d``, ``v = free.free``:
+    column ``j`` fits better than ``k`` iff ``u_j**2 v_k > u_k**2 v_j``.
+    int32 ``free`` (J, R) and ``d`` (R,) in whole units, inside the bounds
+    :func:`check_bestfit_inputs` holds.  ``v`` is 0 only for a zero free
+    vector, which fits only a zero demand, whose ``u`` is 0 everywhere."""
+    u = jnp.sum(free * d[None, :], axis=1)
+    v = jnp.sum(free * free, axis=1)
+    return u * u, jnp.maximum(v, 1)
+
+
+def _bestfit_better(x, y):
+    """The better of two ``(ok, u2, v, idx)`` candidates: a feasible one,
+    then the greater ``u2 / v`` exactly, then the lower index."""
+    okx, ux, vx, ix = x
+    oky, uy, vy, iy = y
+    l, r = _wide_mul(ux, vy), _wide_mul(uy, vx)
+    take = (okx & ~oky) | ((okx == oky) & (
+        _wide_gt(l, r) | (_wide_eq(l, r) & (ix < iy))))
+    return tuple(jnp.where(take, a, b) for a, b in zip(x, y))
+
+
+def bestfit_argmin(u2, v, ok):
+    """The feasible column of least cosine best-fit score, equal keys to
+    the lowest index.  One variadic reduction, integer arithmetic only."""
+    idx = jnp.arange(u2.shape[0], dtype=jnp.int32)
+    init = (jnp.bool_(False), jnp.int32(0), jnp.int32(1), _IBIG)
+    return jax.lax.reduce((ok, u2, v, idx), init, _bestfit_better, (0,))[3]
 
 
 def _argmin_tie_low(s, mask, rtol=1e-6, atol=1e-9):
@@ -263,6 +392,9 @@ def epoch_loop(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
     la = f32(1.0 if lookahead else 0.0)
     tot = jnp.sum(X, axis=1)
     server_specific = kind in ("psdsf", "rpsdsf")
+    if policy == "bestfit":
+        # whole units (checked on the host): the exact best-fit key's input
+        TDi = TD.astype(jnp.int32)
 
     # -- X-independent score pieces (computed once per dispatch) ------------
     if kind == "drf":
@@ -349,6 +481,10 @@ def epoch_loop(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
             j = jnp.min(jnp.where(st.feas[n],
                                   jnp.arange(J, dtype=jnp.int32), _IBIG))
             return n, j, st.pidx, st.pos
+        if policy == "bestfit":
+            n = _argmin1d(st.s, jnp.any(st.feas, axis=1))
+            u2, v = bestfit_keys(st.FREE.astype(jnp.int32), TDi[n])
+            return n, bestfit_argmin(u2, v, st.feas[n]), st.pidx, st.pos
         # rrr: visit the first feasible server at-or-after `pos` in the
         # current round's permutation; wrap to a fresh permutation when the
         # remainder of the round has nothing feasible.  A grant at the LAST
@@ -497,6 +633,8 @@ def epoch_loop_mesh(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
     iteration is a no-op by predication) and RRR's per-server feasibility
     scan with running counts.
     """
+    if policy not in ("pooled", "rrr"):
+        raise ValueError(f"the mesh epoch does not cover {policy!r}")
     global MESH_TRACE_COUNT
     MESH_TRACE_COUNT += 1
     from jax.sharding import PartitionSpec
@@ -1066,6 +1204,7 @@ def run_epoch_async(criterion, policy: str, *, X, D, C, FREE, phi, allowed,
                     shards: int = 1, devices: int = 1,
                     max_steps_cap: int = 16384,
                     preperms: Optional[np.ndarray] = None,
+                    bf_metric: str = "cosine",
                     _perm_rows: Optional[int] = None,
                     _donate: Optional[bool] = None) -> EpochHandle:
     """Dispatch one allocation epoch on device WITHOUT blocking on readback.
@@ -1098,11 +1237,25 @@ def run_epoch_async(criterion, policy: str, *, X, D, C, FREE, phi, allowed,
     :func:`rrr_perm_budget` rows so it can fingerprint them); the dispatch
     then draws nothing up front, only grow-and-replay top-ups — total
     stream consumption is identical to letting the dispatch draw.
+    ``policy="bestfit"`` (``bf_metric`` ``"cosine"``, a global criterion,
+    one device, no shards, no Pallas) first checks that ``FREE`` and the
+    demands are whole units the exact key can order
+    (:func:`check_bestfit_inputs`) and raises where they are not.
     """
     crit = criteria.get_criterion(criterion)
     kind = crit.name
     if kind not in COVERED_CRITERIA or policy not in COVERED_POLICIES:
         raise ValueError(f"fused epoch does not cover {kind}/{policy}")
+    if policy == "bestfit":
+        if not supports(crit, policy, "characterized", "low",
+                        bf_metric=bf_metric, shards=int(shards),
+                        devices=int(devices), use_pallas=use_pallas):
+            raise ValueError(
+                f"fused best-fit covers {'/'.join(BESTFIT_CRITERIA)} with "
+                f"the {'/'.join(BESTFIT_METRICS)} metric on one device, "
+                f"without shards or Pallas; not {kind}/{bf_metric} with "
+                f"shards={shards}, devices={devices}, "
+                f"use_pallas={use_pallas!r}")
     interpret = jax.default_backend() == "cpu"
     if int(devices) > len(jax.devices()):
         raise ValueError(f"epoch asks for a {devices}-device mesh; this "
@@ -1127,6 +1280,8 @@ def run_epoch_async(criterion, policy: str, *, X, D, C, FREE, phi, allowed,
     allowed = np.asarray(allowed, bool)
     N, J = X.shape
     tot = X.sum(axis=1)
+    if policy == "bestfit":
+        check_bestfit_inputs(FREE, TD, tot < wanted, allowed, eps)
 
     bound = grant_bound(TD, FREE, tot, wanted, per_agent_limit)
     if bound == 0:

@@ -907,18 +907,23 @@ class OnlineAllocator:
             (:data:`repro.core.engine.AUTO_KERNEL_MIN_CELLS`); below the
             floor the resolver never imports jax, and RRR always stays on
             the host path (the fused RRR rng pre-draw would make seeded
-            cross-epoch sequences backend/size-dependent).  Never slower
-            than the old numpy default at the benched sizes (asserted in
-            the bench ``--quick`` smoke).
+            cross-epoch sequences backend/size-dependent), as does best-fit
+            (the fused best-fit refuses inputs that are not whole units,
+            see below).  Never slower than the old numpy default at the
+            benched sizes (asserted in the bench ``--quick`` smoke).
           * ``True`` / ``"fused"`` — the device-resident epoch engine
             (:mod:`repro.core.engine_jax`): the whole select -> grant ->
             refresh loop runs as ONE jitted ``lax.while_loop`` dispatch.
             Covers characterized mode, ``tie="low"``, every criterion under
             the pooled/rrr policies (phi, constraints, per_agent_limit
-            included); anything else silently falls back to the numpy
-            incremental path.  Fused RRR pre-draws its server permutations
-            from the allocator rng (see the engine_jax module docstring for
-            the cross-epoch rng-stream caveat).
+            included) and DRF/TSF under best-fit with the cosine metric on
+            one device and one shard; anything else silently falls back to
+            the numpy incremental path.  Fused best-fit raises (before any
+            dispatch) on free resources or demands that are not whole
+            units (``engine_jax.check_bestfit_inputs``).  Fused RRR
+            pre-draws its server permutations from the allocator rng (see
+            the engine_jax module docstring for the cross-epoch rng-stream
+            caveat).
           * ``"pergrant"`` — the legacy per-grant Pallas ``psdsf_score``
             backend (one kernel launch + readback per pick; characterized
             rPS-DSF + pooled only), kept for benchmarking the boundary cost.
@@ -941,7 +946,8 @@ class OnlineAllocator:
 
     # -- the asynchronous epoch pipeline -------------------------------------
 
-    def _resolve_kernel(self, use_kernel, N: int, J: int, tie: str):
+    def _resolve_kernel(self, use_kernel, N: int, J: int, tie: str,
+                        shards: int = 1, devices: int = 1):
         """Resolve a ``use_kernel`` spec to ``False | "pergrant" | "fused"``."""
         if use_kernel in (False, None):
             return False
@@ -951,7 +957,9 @@ class OnlineAllocator:
             from repro.core import engine_jax
 
             return "fused" if engine_jax.supports(
-                self.crit, self.server_policy, self.mode, tie) else False
+                self.crit, self.server_policy, self.mode, tie,
+                bf_metric=self.bf_metric, shards=shards,
+                devices=devices) else False
         if use_kernel == "auto":
             if N * J < AUTO_KERNEL_FLOOR_CELLS:
                 return False        # small epoch: never pay the jax import
@@ -961,6 +969,11 @@ class OnlineAllocator:
                 # differs from the numpy policy's — auto must never make a
                 # seeded run's grant sequences depend on backend or cluster
                 # size.  Fused RRR stays an explicit opt-in.
+                return False
+            if self.server_policy == "bestfit":
+                # fused best-fit raises on inputs its exact key cannot
+                # order (engine_jax.check_bestfit_inputs); auto never picks
+                # a path that may refuse the epoch.  An explicit opt-in.
                 return False
             if not self.device_health.allow_auto_device():
                 # quarantined device path (K consecutive fused failures):
@@ -1174,7 +1187,8 @@ class OnlineAllocator:
             if fw.n_tasks < fw.wanted_tasks:
                 TD[i] = self._true_demand(f)
         TD.setflags(write=False)
-        kernel = self._resolve_kernel(use_kernel, N, len(view.agents), tie)
+        kernel = self._resolve_kernel(use_kernel, N, len(view.agents), tie,
+                                      shards, devices)
         # bracket opens at kernel resolution: every rng draw (fused preperm
         # prefix, host per-round permutations) lands inside it, and a crash
         # before the matching commit/abort record recovers by rewinding to
@@ -1357,6 +1371,7 @@ class OnlineAllocator:
                     true_demands=TD, per_agent_limit=per_agent_limit,
                     lookahead=False, rng=self.rng, shards=shards,
                     devices=devices, preperms=preperms,
+                    bf_metric=self.bf_metric,
                 )
             except Exception as exc:
                 if not _faults.is_device_fault(exc):
@@ -1415,7 +1430,7 @@ class OnlineAllocator:
             phi=view.phi, allowed=view.allowed, wanted=view.wanted,
             true_demands=epoch.TD, per_agent_limit=epoch.per_agent_limit,
             lookahead=False, rng=self.rng, shards=epoch.shards,
-            devices=epoch.devices, preperms=None,
+            devices=epoch.devices, preperms=None, bf_metric=self.bf_metric,
         )
 
     def _recover_commit(self, epoch: InFlightEpoch, exc) -> list[Grant]:

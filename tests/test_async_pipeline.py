@@ -100,11 +100,15 @@ def test_begin_commit_matches_numpy_batched(crit, pol):
 
 def test_begin_commit_host_fallback_matches_sync():
     """Configurations outside device coverage flow through the SAME
-    begin/commit API (host fallback at begin time) with identical grants."""
+    begin/commit API (host fallback at begin time) with identical grants:
+    best-fit after a server-specific criterion, and BF-DRF over shards
+    (the fused best-fit runs on one shard only)."""
     inst = spark_cluster_heterogeneous()
-    for crit, pol in (("rpsdsf", "bestfit"), ("drf", "bestfit")):
+    for crit, pol, shards in (("rpsdsf", "bestfit", 1),
+                              ("drf", "bestfit", 2)):
         ref = _fill(inst, crit, pol, 0, mode="sync", use_kernel=False)
-        got = _fill(inst, crit, pol, 0, mode="async", use_kernel="fused")
+        got = _fill(inst, crit, pol, 0, mode="async", use_kernel="fused",
+                    shards=shards)
         assert ref == got, f"{crit}/{pol}"
 
 

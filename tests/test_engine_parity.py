@@ -414,3 +414,247 @@ def test_cluster_state_slot_reuse_and_growth():
         row, [a in ("a9", "a1") for a in v.agents])
     # phi/wanted rows follow their frameworks
     assert v.phi[v.fids.index("f4")] == 5.0
+
+
+# ---------------------------------------------------------------------------
+# best-fit on the device: the exact cosine key (repro.core.engine_jax)
+# ---------------------------------------------------------------------------
+
+#: the Borg cell's executor demands, in 1/256 of the largest machine
+#: (bench/configs/borg2011-bfdrf.json)
+BORG_CPU = (2, 3, 4, 5, 6, 8, 12, 16)
+BORG_MEM = (1, 2, 3, 4, 5, 6, 8, 12)
+
+
+def _bfdrf_reference():
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "references", "drf-bestfit.py")
+    spec = importlib.util.spec_from_file_location("drf_bestfit_ref", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.epoch
+
+
+def _bestfit_case(seed):
+    """A seeded integer cluster: weights != 1, wants that run out mid-epoch,
+    and collinear free vectors among the machines."""
+    rng = np.random.default_rng(seed)
+    J, W = int(rng.integers(6, 40)), int(rng.integers(2, 12))
+    caps = rng.integers(8, 64, size=(J, 2)).astype(float)
+    caps[1] = 2 * caps[0] if 2 * caps[0].max() <= 255 else caps[0]
+    D = rng.integers(1, 9, size=(W, 2)).astype(float)
+    wanted = rng.integers(1, 25, size=W).astype(float)
+    phi = rng.choice([0.5, 1.0, 2.0], size=W)
+    return caps, D, wanted, phi
+
+
+def _bestfit_alloc(seed, crit, use_kernel, limit=None):
+    caps, D, wanted, phi = _bestfit_case(seed)
+    al = OnlineAllocator(2, criterion=crit, server_policy="bestfit", seed=seed)
+    for j, c in enumerate(caps):
+        al.add_agent(f"a{j:03d}", tuple(c))
+    for n, d in enumerate(D):
+        al.register(f"f{n:03d}", demand=tuple(d), wanted_tasks=int(wanted[n]),
+                    phi=float(phi[n]))
+    grants = al.allocate_batched(per_agent_limit=limit, use_kernel=use_kernel)
+    return [(int(g.fid[1:]), int(g.agent[1:])) for g in grants]
+
+
+@pytest.mark.parametrize("crit", ["drf", "tsf"])
+@pytest.mark.parametrize("limit", [None, 2])
+@pytest.mark.parametrize("seed", range(4))
+def test_device_bestfit_epoch_matches_host_and_reference(crit, limit, seed):
+    """The fused best-fit epoch runs on the device (one dispatch) and
+    equals the numpy engine's grant sequence; with no per-agent limit, the
+    DRF sequence also equals the plain BF-DRF reference of the benchmark."""
+    pytest.importorskip("jax")
+    from repro.core import engine_jax
+
+    host = _bestfit_alloc(seed, crit, False, limit)
+    d0 = engine_jax.DISPATCH_COUNT
+    dev = _bestfit_alloc(seed, crit, "fused", limit)
+    assert engine_jax.DISPATCH_COUNT == d0 + 1
+    assert len(host) > 10 and dev == host
+    if crit == "drf" and limit is None:
+        caps, D, wanted, phi = _bestfit_case(seed)
+        ref = _bfdrf_reference()(
+            {}, D=D, tot=np.zeros(len(D)),
+            wanted=wanted, phi=phi, free=caps.copy(), ctot=caps.sum(axis=0))
+        assert ref == host
+
+
+def _borg_grid(d):
+    """Every free vector of the Borg grid [0, 256]^2 that fits ``d``."""
+    a = np.stack(np.meshgrid(np.arange(257), np.arange(257), indexing="ij"),
+                 axis=-1).reshape(-1, 2)
+    return a[(a >= np.asarray(d)).all(axis=1)]
+
+
+def test_device_bestfit_key_orders_the_borg_grid_as_float64():
+    """Exhaustive: for each Borg demand, sort every fitting free vector by
+    the host's float64 cosine score (ties, within the host's 1e-12, by
+    index); the device's exact key must call each adjacent pair strictly
+    ordered where float64 orders it, and equal where float64 ties it."""
+    pytest.importorskip("jax")
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import criteria, engine_jax
+
+    @jax.jit
+    def order(u2, v, k, m):
+        left = engine_jax._wide_mul(u2[k], v[m])
+        right = engine_jax._wide_mul(u2[m], v[k])
+        return engine_jax._wide_gt(left, right), engine_jax._wide_eq(
+            left, right)
+
+    pairs = ties = 0
+    for c in BORG_CPU:
+        for m_ in BORG_MEM:
+            d = np.array([c, m_])
+            a = _borg_grid(d)
+            score = criteria.bestfit_scores(a.astype(float), d.astype(float))
+            idx = np.lexsort((np.arange(len(a)), score))
+            tied = np.diff(score[idx]) <= 1e-12
+            u2, v = engine_jax.bestfit_keys(jnp.asarray(a, jnp.int32),
+                                            jnp.asarray(d, jnp.int32))
+            gt, eq = order(u2, v, jnp.asarray(idx[:-1]),
+                           jnp.asarray(idx[1:]))
+            gt, eq = np.asarray(gt), np.asarray(eq)
+            np.testing.assert_array_equal(eq, tied, err_msg=f"d={d}")
+            np.testing.assert_array_equal(gt, ~tied, err_msg=f"d={d}")
+            pairs += len(tied)
+            ties += int(tied.sum())
+    assert pairs > 3_000_000 and ties > 0
+
+
+@pytest.mark.parametrize("d", [(2, 1), (16, 12), (5, 12), (3, 3)])
+def test_device_bestfit_argmin_takes_the_lowest_tied_index(d):
+    """On masked samples of the Borg grid (collinear free vectors tie
+    exactly), the device select is the host's float64 argmin with ties to
+    the lowest index."""
+    pytest.importorskip("jax")
+    import jax.numpy as jnp
+
+    from repro.core import criteria, engine_jax
+    from repro.core.policies import argmin_masked
+
+    rng = np.random.default_rng(sum(d))
+    d = np.asarray(d)
+    grid = _borg_grid(d)
+    for _ in range(20):
+        a = grid[rng.choice(len(grid), size=512)]
+        a[rng.choice(512, 64)] = a[rng.integers(512)]       # exact repeats
+        ok = rng.random(512) < 0.5
+        score = criteria.bestfit_scores(a.astype(float), d.astype(float))
+        want = argmin_masked(score, ok, "low", None)
+        j = engine_jax.bestfit_argmin(
+            *engine_jax.bestfit_keys(jnp.asarray(a, jnp.int32),
+                                     jnp.asarray(d, jnp.int32)),
+            jnp.asarray(ok))
+        assert int(j) == want
+    collinear = np.array([[9, 9], [8, 4], [4, 2], [12, 6], [6, 3]])
+    j = engine_jax.bestfit_argmin(
+        *engine_jax.bestfit_keys(jnp.asarray(collinear, jnp.int32),
+                                 jnp.asarray([2, 1], jnp.int32)),
+        jnp.asarray([True, False, True, True, True]))
+    assert int(j) == 2
+
+
+def test_device_bestfit_key_has_no_division_sqrt_or_matmul():
+    pytest.importorskip("jax")
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import engine_jax
+
+    def prims(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            out.add(eqn.primitive.name)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                prims(sub, out)
+        return out
+
+    closed = jax.make_jaxpr(lambda f, d, ok: engine_jax.bestfit_argmin(
+        *engine_jax.bestfit_keys(f, d), ok))(
+        jnp.zeros((16, 2), jnp.int32), jnp.ones(2, jnp.int32),
+        jnp.ones(16, bool))
+    used = prims(closed.jaxpr, set())
+    assert "reduce" in used and "mul" in used        # the variadic select
+    assert not used & {"div", "sqrt", "rsqrt", "dot_general", "rem", "pow",
+                       "integer_pow", "exp", "log"}, used
+
+
+@pytest.mark.parametrize("crit,metric,kw,ok", [
+    ("drf", "cosine", {}, True),
+    ("tsf", "cosine", {}, True),
+    ("drf", "tight", {}, False),
+    ("drf", "align", {}, False),
+    ("rpsdsf", "cosine", {}, False),
+    ("psdsf", "cosine", {}, False),
+    ("drf", "cosine", {"shards": 2}, False),
+    ("drf", "cosine", {"devices": 2}, False),
+    ("drf", "cosine", {"use_pallas": True}, False),
+    ("drf", "cosine", {"use_pallas": "persistent"}, False),
+])
+def test_device_bestfit_coverage_is_explicit(crit, metric, kw, ok):
+    """supports() covers best-fit for DRF/TSF with the cosine metric on one
+    device only; the engine refuses the rest outright."""
+    pytest.importorskip("jax")
+    from repro.core import engine_jax
+
+    assert engine_jax.supports(crit, "bestfit", "characterized", "low",
+                               bf_metric=metric, **kw) is ok
+    if ok:
+        return
+    with pytest.raises(ValueError, match="best-fit"):
+        engine_jax.run_epoch_async(
+            crit, "bestfit", X=np.zeros((1, 2)), D=np.ones((1, 2)),
+            C=np.full((2, 2), 4.0), FREE=np.full((2, 2), 4.0),
+            phi=np.ones(1), allowed=np.ones((1, 2), bool),
+            wanted=np.array([3.0]), true_demands=np.ones((1, 2)),
+            bf_metric=metric, **kw)
+
+
+@pytest.mark.parametrize("cap,demand,match", [
+    ((8.5, 10.0), (1.0, 1.0), "whole units"),
+    ((8.0, 10.0), (1.0, 0.5), "whole units"),
+    ((40000.0, 4.0), (1.0, 1.0), "orders free"),
+])
+def test_device_bestfit_refuses_inputs_its_key_cannot_order(cap, demand,
+                                                             match):
+    """Under use_kernel="fused", best-fit inputs that are not whole units
+    (or exceed the key's bounds) raise before the dispatch, with the epoch
+    undone; they never fall back to the host in silence."""
+    pytest.importorskip("jax")
+    from repro.core import engine_jax
+
+    al = OnlineAllocator(2, criterion="drf", server_policy="bestfit", seed=0)
+    al.add_agent("a0", cap)
+    al.add_agent("a1", (8.0, 8.0))
+    al.register("f0", demand=demand, wanted_tasks=4)
+    state0 = al.rng.bit_generator.state
+    d0 = engine_jax.DISPATCH_COUNT
+    with pytest.raises(ValueError, match=match):
+        al.allocate_batched(use_kernel="fused")
+    assert engine_jax.DISPATCH_COUNT == d0
+    assert al.rng.bit_generator.state == state0
+    assert al.frameworks["f0"].n_tasks == 0
+    assert al.allocate_batched(use_kernel=False)      # the host still serves
+
+
+def test_device_bestfit_checks_only_what_the_key_can_meet():
+    """Columns no wanting row may use or fit stay out of the check: an
+    epoch with nothing feasible (as the benchmark's warm-up dispatches)
+    runs whatever those columns hold."""
+    pytest.importorskip("jax")
+    from repro.core import engine_jax
+
+    free = np.full((4, 2), 1e9)
+    seq = engine_jax.run_epoch(
+        "drf", "bestfit", X=np.zeros((2, 4)), D=np.ones((2, 2)), C=free,
+        FREE=free, phi=np.ones(2), allowed=np.zeros((2, 4), bool),
+        wanted=np.array([5.0, 0.0]), true_demands=np.ones((2, 2)))
+    assert seq == []

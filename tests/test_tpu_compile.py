@@ -74,7 +74,8 @@ def _epoch_args(sharding, N, J, perm_rows=1):
             S((), i32), S((), i32), S((), f32))
 
 
-@pytest.mark.parametrize("kind,policy", [("rpsdsf", "pooled"), ("drf", "rrr")])
+@pytest.mark.parametrize("kind,policy", [("rpsdsf", "pooled"), ("drf", "rrr"),
+                                         ("drf", "bestfit")])
 @pytest.mark.parametrize("N,J", [(2048, 1024), (2048, 16384)])
 def test_epoch_loop_compiles_for_v5e(one_chip, kind, policy, N, J):
     """The served fused epoch (donated buffers, as on the chip) at the
