@@ -86,6 +86,15 @@ grow-and-replay path re-uploads the segment-start state from a host-side
 snapshot, so donation is safe under RRR too (the pre-drawn permutation
 stack is never in the donated set).
 
+Staging by content: the two (N, J) inputs go to the device at the size of
+what they hold and are expanded there, bit-for-bit the padded f32 X and
+padded bool ``allowed`` a dense upload would give.  X goes as the flat
+(Np, Jp) index and f32 value of each non-zero, in fixed chunks of
+:data:`STAGE_CHUNK` scattered into a zero buffer by one small program per
+padded shape; ``allowed`` goes packed to bits and is unpacked on the
+device.  The cost follows the support, which is tiny for a fleet's
+allocation matrix; a fully dense X would send 8 bytes per cell, not 4.
+
 Asynchronous epochs and commit-point semantics
 ----------------------------------------------
 :func:`run_epoch_async` issues the SAME host prep + device dispatch as
@@ -1012,6 +1021,68 @@ def _pad(a, n, axis, value):
     return np.pad(a, widths, constant_values=value)
 
 
+#: entries of X's support sent per upload chunk, an int32 flat index and
+#: an f32 value each.  The length is fixed per padded shape and every
+#: staged X sends at least one chunk, so one scatter program per (Np, Jp)
+#: stages every epoch of that shape, whatever its support.
+STAGE_CHUNK = 1 << 16
+
+
+def _scatter_chunk(buf, idx, val):
+    """``buf`` with ``val`` written at the flat indices ``idx`` (sorted,
+    distinct); indices past the end, a last chunk's padding, are dropped."""
+    flat = buf.reshape(-1).at[idx].set(
+        val, mode="drop", indices_are_sorted=True, unique_indices=True)
+    return flat.reshape(buf.shape)
+
+
+@functools.lru_cache(maxsize=None)
+def _scatter_jit():
+    # the buffer is the staging's own, so it is donated wherever the
+    # backend reuses donated buffers
+    donate = (0,) if jax.default_backend() != "cpu" else ()
+    return jax.jit(_scatter_chunk, donate_argnums=donate)
+
+
+@jax.jit
+def _unpack_bits(bits):
+    return jnp.unpackbits(bits, axis=1).astype(bool)
+
+
+def _stage_x(X, Np, Jp):
+    """Host ``X`` as the zero-padded f32 (Np, Jp) device array, sent as its
+    support: the flat (Np, Jp) index and f32 value of each non-zero
+    (``!= 0``: NaN is kept, -0.0 is zero), in chunks scattered into a zero
+    buffer on the device.  ``X`` may be any (n, j) with n <= Np, j <= Jp."""
+    flat = np.flatnonzero(X != 0)     # several times np.nonzero's speed
+    rows, cols = np.divmod(flat, X.shape[1])
+    n = flat.size
+    tracing.count("engine_jax.staged_nonzeros", n)
+    chunk = min(STAGE_CHUNK, Np * Jp)
+    k = max(1, -(-n // chunk))
+    idx = np.empty(k * chunk, np.int32)
+    np.add(rows * Jp, cols, out=idx[:n], casting="unsafe")
+    # distinct out-of-range indices keep the padding sorted and unique
+    idx[n:] = np.arange(Np * Jp, Np * Jp + k * chunk - n, dtype=np.int32)
+    val = np.zeros(k * chunk, np.float32)
+    val[:n] = X[rows, cols]
+    scatter = _scatter_jit()
+    buf = jnp.zeros((Np, Jp), jnp.float32)
+    for i in range(0, k * chunk, chunk):
+        buf = scatter(buf, _put(idx[i:i + chunk]), _put(val[i:i + chunk]))
+    return buf
+
+
+def _stage_allowed(allowed, Np, Jp):
+    """Host ``allowed`` (N, J) as the False-padded (Np, Jp) device mask,
+    sent at one bit per cell (``np.packbits``, rows padded with zero bits)
+    and unpacked on the device."""
+    bits = np.zeros((Np, Jp // 8), np.uint8)
+    packed = np.packbits(allowed, axis=1)
+    bits[:packed.shape[0], :packed.shape[1]] = packed
+    return _unpack_bits(_put(bits))
+
+
 def grant_bound(TD, FREE, tot, wanted, per_agent_limit=None) -> int:
     """Upper bound on grants this epoch (sizes the device-side sequence).
 
@@ -1053,7 +1124,7 @@ class _EpochRun:
     chained overflow segments exactly like the old synchronous loop did."""
 
     def __init__(self, *, fn, kind, policy, lookahead, use_limit, use_pallas,
-                 interpret, shards, J, limit, eps, draw, consts,
+                 interpret, shards, J, shape, limit, eps, draw, consts,
                  perms, bound, max_steps_cap, snap, donate=False,
                  devices=1):
         self.fn = fn                # _jitted(donate) / _jitted_mesh()
@@ -1064,6 +1135,7 @@ class _EpochRun:
         self.devices = devices      # >1: mesh dispatch (epoch_loop_mesh)
         self.donate = donate
         self.J, self.limit, self.eps = J, limit, eps
+        self.shape = shape          # the padded (Np, Jp)
         self.draw = draw            # rng-stream permutation drawer (RRR)
         self.consts = consts        # (dD, dTD, dC, dphi, dwanted, dallowed)
         self.perms = perms
@@ -1072,7 +1144,7 @@ class _EpochRun:
         self.max_steps_cap = max_steps_cap
         # host-side snapshot of the segment-start state: with donation the
         # dispatch invalidates its input buffers, so a grow-and-replay round
-        # re-uploads from here (RRR only; pooled never replays).  WITHOUT
+        # stages X again from here (RRR only; pooled never replays).  WITHOUT
         # donation the dispatch inputs stay valid, so the replay path keeps
         # device-array references instead and no host copy is ever made —
         # the CPU backend (donation off) previously paid that O((N+J)*R)
@@ -1137,7 +1209,7 @@ class _EpochRun:
                     with tracing.span("engine_jax.upload"):
                         if self.donate:
                             Xs, FREEs, useds = self.snap
-                            self.dispatch(_put(Xs, jnp.float32),
+                            self.dispatch(_stage_x(Xs, *self.shape),
                                           _put(FREEs, jnp.float32),
                                           _put(useds, jnp.int32))
                         else:
@@ -1286,7 +1358,8 @@ def run_epoch_async(criterion, policy: str, *, X, D, C, FREE, phi, allowed,
     bound = grant_bound(TD, FREE, tot, wanted, per_agent_limit)
     if bound == 0:
         return EpochHandle(seq=[])
-    # staging: pad, cast and upload the epoch's inputs, then launch
+    # staging: pad, cast and upload the epoch's inputs (X and allowed by
+    # their content), then launch
     with tracing.span("engine_jax.upload"):
         Np, Jp = _bucket(N), _bucket(J)
         limit = np.int32(0 if per_agent_limit is None else per_agent_limit)
@@ -1296,14 +1369,12 @@ def run_epoch_async(criterion, policy: str, *, X, D, C, FREE, phi, allowed,
         shards = min(shards, Np, Jp)                 # pow2s: divides both
         devices = min(devices, Jp)                   # pow2s: divides Jp
 
-        Xp = _pad(_pad(X, Np, 0, 0.0), Jp, 1, 0.0)
         Dp = _pad(D, Np, 0, 0.0)
         TDp = _pad(TD, Np, 0, 0.0)
         Cp = _pad(C, Jp, 0, 0.0)
         FREEp = _pad(FREE, Jp, 0, 0.0)
         phip = _pad(phi, Np, 0, 1.0)
         wantedp = _pad(wanted, Np, 0, 0.0)   # padded frameworks want nothing
-        allowedp = _pad(_pad(allowed, Np, 0, False), Jp, 1, False)
         usedp = np.zeros(Jp, np.int32)
 
         def _draw_perms(k: int) -> np.ndarray:
@@ -1346,16 +1417,18 @@ def run_epoch_async(criterion, policy: str, *, X, D, C, FREE, phi, allowed,
         # device across chained segments (only the grant sequence is read
         # back).
         consts = (_put(Dp, f32), _put(TDp, f32), _put(Cp, f32),
-                  _put(phip, f32), _put(wantedp, f32), _put(allowedp))
+                  _put(phip, f32), _put(wantedp, f32),
+                  _stage_allowed(allowed, Np, Jp))
         run = _EpochRun(
             fn=fn, kind=kind, policy=policy, lookahead=lookahead,
             use_limit=use_limit, use_pallas=use_pallas, interpret=interpret,
-            shards=shards, devices=devices, J=J, limit=limit, eps=eps,
+            shards=shards, devices=devices, J=J, shape=(Np, Jp),
+            limit=limit, eps=eps,
             draw=_draw_perms, consts=consts, perms=perms, bound=bound,
             max_steps_cap=max_steps_cap, donate=donate,
-            snap=(Xp, FREEp, usedp) if policy == "rrr" and donate else None,
+            snap=(X, FREEp, usedp) if policy == "rrr" and donate else None,
         )
-        run.dispatch(_put(Xp, f32), _put(FREEp, f32), _put(usedp))
+        run.dispatch(_stage_x(X, Np, Jp), _put(FREEp, f32), _put(usedp))
     return EpochHandle(run=run)
 
 

@@ -169,7 +169,14 @@ def _pow2(n, lo=8):
                                               ("pooled", "rpsdsf")])
 def test_a_served_epoch_has_the_span_tree_and_counts_its_bytes(
         recorder, policy, criterion):
+    from repro.core import engine_jax
+
     service = _service(policy, criterion)
+    # a first epoch places executors, so the measured one stages an X with
+    # a support: one non-zero per (framework, machine) pair it holds
+    held = _epoch(service, 2, 2, prefix="w")
+    nonzeros = len({(g.fid, g.agent) for g in held})
+    tracing.reset()
     n_fw, executors, J, R = 5, 3, 10, 2
     grants = _epoch(service, n_fw, executors)
     assert len(grants) == n_fw * executors
@@ -179,7 +186,7 @@ def test_a_served_epoch_has_the_span_tree_and_counts_its_bytes(
     epoch = [r for r in recs if r.name != "service.complete"]
     assert sorted(r.name for r in epoch) == sorted(EPOCH_TREE)
     root = next(r for r in epoch if r.name == "service.drain_epoch")
-    assert root.attrs["epoch"] == 0
+    assert root.attrs["epoch"] == 1
     for r in epoch:
         want = EPOCH_TREE[r.name]
         assert (by_id[r.parent].name if r.parent else None) == want
@@ -187,19 +194,26 @@ def test_a_served_epoch_has_the_span_tree_and_counts_its_bytes(
     done = next(r for r in recs if r.name == "service.complete")
     assert done.parent == 0 and done.root == done.id
 
-    # bytes handed to the device, from the padded shapes: X (f32) and
-    # allowed (bool) over (Np, Jp); D, TD (Np, R) and C, FREE (Jp, R) f32;
-    # phi, wanted (Np,) f32; used (Jp,) i32; the permutation stack (K, Jp)
-    # i32; pidx, pos, J, limit, eps at 4 bytes each
-    Np, Jp = _pow2(n_fw), _pow2(J)
-    # capacity is ample, so the grant bound is the wanted deficit; RRR
-    # stacks one permutation per J grants plus four of slack, pow2-rounded
+    # bytes handed to the device, from the padded shapes: X's support as
+    # one chunk of int32 flat indices and f32 values (a chunk is at most
+    # Np * Jp entries); allowed at one bit per cell over (Np, Jp); D, TD
+    # (Np, R) and C, FREE (Jp, R) f32; phi, wanted (Np,) f32; used (Jp,)
+    # i32; the permutation stack (K, Jp) i32; pidx, pos, J, limit, eps at 4
+    # bytes each
+    Np, Jp = _pow2(2 + n_fw), _pow2(J)
+    chunk = min(engine_jax.STAGE_CHUNK, Np * Jp)
+    # capacity is ample and the held frameworks want nothing more, so the
+    # grant bound is the new deficit; RRR stacks one permutation per J
+    # grants plus four of slack, pow2-rounded
     K = _pow2(4 + 4 * -(-n_fw * executors // J)) if policy == "rrr" else 1
-    expect = (4 * Np * Jp + Np * Jp + 2 * 4 * Np * R + 2 * 4 * Jp * R
+    expect = (8 * chunk + Np * Jp // 8 + 2 * 4 * Np * R + 2 * 4 * Jp * R
               + 2 * 4 * Np + 4 * Jp + 4 * K * Jp + 5 * 4)
     upload = next(r for r in epoch if r.name == "engine_jax.upload")
     assert upload.attrs["engine_jax.upload_bytes"] == expect
     assert tracing.totals()["engine_jax.upload_bytes"] == expect
+    assert nonzeros > 0
+    assert upload.attrs["engine_jax.staged_nonzeros"] == nonzeros
+    assert tracing.totals()["engine_jax.staged_nonzeros"] == nonzeros
 
 
 def test_an_epoch_cache_hit_uploads_nothing(recorder):
@@ -251,6 +265,8 @@ def test_service_stats_report_epoch_seconds_and_trace_counters(recorder):
     assert drains[0].t1 - drains[0].t0 <= service.epoch_s[-1]
     c = service.counters()
     assert c["upload_bytes"] == tracing.totals()["engine_jax.upload_bytes"]
+    assert c["staged_nonzeros"] == tracing.totals().get(
+        "engine_jax.staged_nonzeros", 0)
     assert c["upload_bytes"] > 0 and c["trace_dropped"] == 0
     assert {"lowerings", "compile_s"} <= set(c)
 
