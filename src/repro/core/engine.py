@@ -41,34 +41,24 @@ from repro.core.policies import make_policy
 _KBIG = 3.0e38  # unsatisfiable-demand sentinel for the kernel backend
                 # (matches repro.kernels.psdsf_score BIG up to headroom)
 
-#: Measured crossover for ``use_kernel="auto"`` path selection, in epoch
-#: cells (N frameworks x J agents).  The candidates are the legacy per-grant
+#: Crossover for ``use_kernel="auto"`` path selection, in epoch cells
+#: (N frameworks x J agents).  The candidates are the legacy per-grant
 #: recompute, this numpy incremental epoch, and the fused device epoch
-#: (:mod:`repro.core.engine_jax`); per BENCH_allocator.json the per-grant
-#: path never wins (batched is 18-52x faster at every benched size), so the
+#: (:mod:`repro.core.engine_jax`); the per-grant path is never picked, so the
 #: auto rule reduces to batched-vs-device.  Below ``AUTO_KERNEL_FLOOR_CELLS``
-#: the resolver returns the numpy epoch without even importing jax.  On the
-#: CPU backend the numpy epoch beats the device epoch at BOTH benched sizes
-#: (50x25: ~21.6k vs ~10.2k grants/s; 200x100: ~18.5k vs ~11.9k for
-#: drf/rrr), so its crossover sits past the 1000x400 ``--big`` point, at
-#: fleet scale where the O(N*J) argmin-per-grant select dominates the numpy
-#: epoch; accelerator backends flip far earlier (dispatch overhead is fixed
-#: while the numpy host loop is not).
+#: the resolver returns the numpy epoch without even importing jax.  These
+#: crossovers come from CPU runs (``benchmarks/allocator_bench.py``) and have
+#: not been measured on the chip (ROADMAP S5).
 AUTO_KERNEL_MIN_CELLS = {"cpu": 1 << 19, "default": 1 << 13}
 #: below the smallest per-backend threshold the resolver's answer is
 #: "numpy" on every backend, so it never needs to import jax to know it
 AUTO_KERNEL_FLOOR_CELLS = min(AUTO_KERNEL_MIN_CELLS.values())
-#: Floors for the PARTITIONED fused dispatches under ``use_kernel="auto"``:
-#: a requested shard count / device-mesh size is honored only at or above
-#: these epoch-cell sizes and silently collapses to the plain fused path
-#: below them.  Both partitionings pay a fixed per-grant toll — the sharded
-#: select a two-pass tile reduce, the mesh a cross-device collective
-#: rendezvous — that the measured crossovers (BENCH_allocator.json) only
-#: amortize near fleet scale: sharded selects lose below the ~2000x1000
-#: point (1.14x at it) and the mesh's per-grant collectives dwarf the
-#: O(N + J/devices) body at toy sizes while winning 1.5x+ at the fleet
-#: point.  Explicit ``shards=``/``devices=`` requests are never clamped.
-AUTO_SHARD_MIN_CELLS = 1 << 20
+#: Floor for the device-mesh fused dispatch under ``use_kernel="auto"``: a
+#: requested mesh size is honored only at or above this many epoch cells and
+#: collapses to one device below it, since the mesh pays a cross-device
+#: collective per grant.  Like the kernel crossover it comes from CPU runs
+#: and has not been measured on the chip (ROADMAP S5).  An explicit
+#: ``devices=`` request is never clamped.
 AUTO_MESH_MIN_CELLS = 1 << 20
 
 # lazily-bound kernel backend modules: importing them pulls in jax, which the

@@ -19,7 +19,7 @@ and mid-epoch exhaustion of ``wanted`` — and the global criteria (DRF,
 TSF) under ``bestfit`` with the ``cosine`` metric (BF-DRF).  Oblivious
 mode (inferred-demand drift), the other best-fit metrics and best-fit
 after a server-specific criterion stay on the host paths; best-fit is
-refused on the device mesh, with ``shards > 1`` and with ``use_pallas``.
+refused on the device mesh.
 
 Best-fit on device
 ------------------
@@ -68,14 +68,7 @@ binary-exact instances and small totals, and is best-effort beyond that.
 Feasibility uses the numpy path's absolute ``eps`` against f32 ``FREE``
 arithmetic, which is exact for the paper's quantized (quarter-multiple)
 demand vectors; for non-dyadic demands the online allocator re-validates
-every fused grant in f64 before applying it.  With ``use_pallas=True``
-(strictly opt-in) the masked-argmin
-reductions run as Pallas kernels (``repro.kernels.psdsf_score``), which
-reduce per 128-wide tile and then across tile partials: the winner matches
-lexicographic order within one tile, but EXACT ties that straddle a tile
-boundary may resolve to a different (equal-score) pair than the numpy path
-— same caveat as the per-grant ``psdsf_argmin`` backend.  Keep the default
-jnp reductions when bit-parity with numpy matters at > 128-wide shapes.
+every fused grant in f64 before applying it.
 
 Shape bucketing: the host wrapper pads N and J up to powers of two (>= 8)
 and ``max_steps`` to a power-of-two bucket, so growing a fleet within its
@@ -125,20 +118,6 @@ device loop always scores the post-revocation state and the
 semantics are unchanged.  While an epoch is in flight, revocations are
 REFUSED (``OnlineAllocator.revoke_executor`` raises; they are never
 deferred), which is what keeps a dispatched epoch's inputs authoritative.
-
-Sharded select
---------------
-With ``shards=K > 1`` the in-loop selects partition the padded agent axis
-(and, for the 1-D criterion selects, the framework axis) into K equal
-shards: each iteration runs a per-shard masked min (a ``vmap`` over the
-leading shard axis — the single-device stand-in for a ``shard_map``
-placement), cross-shard-reduces the partial minima into the global
-tie-tolerance threshold, and then reduces the per-shard first-qualifying
-indices to the global lexicographic winner.  The two-pass reduction applies
-exactly the same f32 comparisons as the unsharded ``_argmin_tie_low``, so
-grant sequences are unchanged (parity-gated).  ``shards`` is part of the
-jit key: the first epoch at a new shard count traces once per shape bucket,
-after which the executable is reused.
 """
 from __future__ import annotations
 
@@ -188,8 +167,7 @@ BESTFIT_NORM_LIMIT = 2**30
 
 
 def supports(criterion, policy: str, mode: str, tie: str, *,
-             bf_metric: str = "cosine", shards: int = 1, devices: int = 1,
-             use_pallas=False) -> bool:
+             bf_metric: str = "cosine", devices: int = 1) -> bool:
     """Can the fused device epoch serve this configuration?"""
     try:
         name = criteria.get_criterion(criterion).name
@@ -197,7 +175,7 @@ def supports(criterion, policy: str, mode: str, tie: str, *,
         return False
     if policy == "bestfit" and (
             name not in BESTFIT_CRITERIA or bf_metric not in BESTFIT_METRICS
-            or shards > 1 or devices > 1 or use_pallas):
+            or devices > 1):
         return False
     return (name in COVERED_CRITERIA and policy in COVERED_POLICIES
             and mode == "characterized" and tie == "low")
@@ -310,49 +288,6 @@ def _argmin_tie_low(s, mask, rtol=1e-6, atol=1e-9):
     return jnp.min(jnp.where(masked <= m + tol, idx, _IBIG))
 
 
-def _argmin_tie_low_sharded(s, mask, shards, rtol=1e-6, atol=1e-9):
-    """Sharded :func:`_argmin_tie_low`: per-shard masked min (vmap over a
-    leading shard axis), cross-shard reduce of the partial minima into the
-    global threshold, then reduce the per-shard first-qualifying indices.
-    f32 min is exactly associative/commutative, so the winner is identical
-    to the unsharded reduction."""
-    L = s.shape[0]
-    Ls = L // shards
-    masked = jnp.where(mask, s.astype(jnp.float32), _BIG).reshape(shards, Ls)
-    m = jnp.min(jax.vmap(jnp.min)(masked))         # cross-shard reduce #1
-    tol = atol + rtol * jnp.abs(m)
-    idx = jnp.arange(Ls, dtype=jnp.int32)
-    local = jax.vmap(
-        lambda row: jnp.min(jnp.where(row <= m + tol, idx, _IBIG)))(masked)
-    valid = local < _IBIG
-    offs = jnp.arange(shards, dtype=jnp.int32) * Ls
-    # clamp invalid shards BEFORE adding the offset (offs + _IBIG overflows)
-    return jnp.min(jnp.where(valid, offs + jnp.where(valid, local, 0), _IBIG))
-
-
-def _argmin2d_tie_low_sharded(mat, mask, shards, rtol=1e-6, atol=1e-9):
-    """Sharded (N, J) masked argmin, agents partitioned into ``shards``
-    column blocks.  Within a shard the first-qualifying LOCAL flat index
-    (row-major over (N, J/K)) picks the same (n, j) pair as lexicographic
-    (n, j) order, so reducing the per-shard winners by the GLOBAL flat key
-    ``n * J + j`` reproduces the unsharded flattened tie-break exactly."""
-    N, J = mat.shape
-    Js = J // shards
-    m3 = (jnp.where(mask, mat.astype(jnp.float32), _BIG)
-          .reshape(N, shards, Js).transpose(1, 0, 2).reshape(shards, N * Js))
-    m = jnp.min(jax.vmap(jnp.min)(m3))
-    tol = atol + rtol * jnp.abs(m)
-    idx = jnp.arange(N * Js, dtype=jnp.int32)
-    local = jax.vmap(
-        lambda row: jnp.min(jnp.where(row <= m + tol, idx, _IBIG)))(m3)
-    valid = local < _IBIG
-    lf = jnp.where(valid, local, 0)
-    n, jl = lf // Js, lf % Js
-    offs = jnp.arange(shards, dtype=jnp.int32) * Js
-    key = jnp.min(jnp.where(valid, n * J + offs + jl, _IBIG))
-    return key // J, key % J
-
-
 class _EpochState(NamedTuple):
     X: jax.Array        # (N, J) f32 allocation counts
     tot: jax.Array      # (N,) f32
@@ -371,8 +306,7 @@ class _EpochState(NamedTuple):
 
 def epoch_loop(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
                pidx0, pos0, j_real, limit, eps, *, kind: str, policy: str,
-               lookahead: bool, use_limit: bool, use_pallas: bool,
-               interpret: bool, max_steps: int, shards: int = 1):
+               lookahead: bool, use_limit: bool, max_steps: int):
     """Traceable core: run one allocation epoch entirely under lax control
     flow.  Returns ``(ns, js, count, X, tot, FREE, used, pidx, pos)``.
 
@@ -387,8 +321,6 @@ def epoch_loop(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
     """
     global TRACE_COUNT
     TRACE_COUNT += 1
-    if shards > 1 and (X.shape[0] % shards or X.shape[1] % shards):
-        shards = 1      # static shapes: resolved at trace time, no retrace
     f32 = jnp.float32
     X = X.astype(f32)
     D = D.astype(f32)
@@ -433,65 +365,18 @@ def epoch_loop(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
     if use_limit:
         feas0 = feas0 & (used < limit)[None, :]
 
-    if use_pallas == "persistent":
-        # whole-epoch persistent kernel: the engine computes the f32 score
-        # / feasibility init above (bit-identical to this loop's), the
-        # kernel owns everything after it.
-        from repro.kernels.epoch_persistent.ops import persistent_epoch
-
-        aux = (unit if kind == "drf"
-               else denom if kind == "tsf" else jnp.zeros((N,), f32))
-        return persistent_epoch(
-            X, tot, FREE, cap0, dom0, s0, feas0, used, D, TD, C, phi,
-            wanted, allowed, perms, aux, pidx0, pos0, j_real, limit, eps,
-            kind=kind, policy=policy, lookahead=lookahead,
-            use_limit=use_limit, max_steps=max_steps, interpret=interpret)
-
-    if use_pallas:
-        from repro.kernels.psdsf_score.kernel import (
-            masked_argmin1d_tiles, masked_argmin2d_tiles)
-        from repro.kernels.psdsf_score.ops import _block
-
-        bn = _block(N, 128)
-        bj = _block(J, 128)
-
-    def _argmin1d(vec, ok):
-        """Masked argmin over a vector (RRR visit / global criterion)."""
-        if shards > 1:
-            return _argmin_tie_low_sharded(vec, ok, shards)
-        if use_pallas and N % bn == 0:
-            mins, args = masked_argmin1d_tiles(
-                vec.astype(f32), ok.astype(jnp.int32), bn=bn,
-                interpret=interpret)
-            k = jnp.argmin(mins)
-            return args[k]
-        return _argmin_tie_low(vec, ok)
-
-    def _argmin2d(mat, ok):
-        """Masked argmin over the (N, J) score matrix (pooled)."""
-        if shards > 1:
-            return _argmin2d_tie_low_sharded(mat, ok, shards)
-        if use_pallas and N % bn == 0 and J % bj == 0:
-            mins, args = masked_argmin2d_tiles(
-                mat.astype(f32), ok.astype(jnp.int32), bn=bn, bj=bj,
-                interpret=interpret)
-            k = jnp.argmin(mins.reshape(-1))
-            enc = args.reshape(-1)[k]
-            return enc // J, enc % J
-        flat = _argmin_tie_low(mat.reshape(-1), ok.reshape(-1))
-        return flat // J, flat % J
-
     def _select(st: _EpochState):
         if policy == "pooled":
             if server_specific:
-                return _argmin2d(st.s, st.feas) + (st.pidx, st.pos)
+                flat = _argmin_tie_low(st.s.reshape(-1), st.feas.reshape(-1))
+                return flat // J, flat % J, st.pidx, st.pos
             row_ok = jnp.any(st.feas, axis=1)
-            n = _argmin1d(st.s, row_ok)
+            n = _argmin_tie_low(st.s, row_ok)
             j = jnp.min(jnp.where(st.feas[n],
                                   jnp.arange(J, dtype=jnp.int32), _IBIG))
             return n, j, st.pidx, st.pos
         if policy == "bestfit":
-            n = _argmin1d(st.s, jnp.any(st.feas, axis=1))
+            n = _argmin_tie_low(st.s, jnp.any(st.feas, axis=1))
             u2, v = bestfit_keys(st.FREE.astype(jnp.int32), TDi[n])
             return n, bestfit_argmin(u2, v, st.feas[n]), st.pidx, st.pos
         # rrr: visit the first feasible server at-or-after `pos` in the
@@ -512,7 +397,7 @@ def epoch_loop(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
         eff_ok = jnp.where(wrap, server_ok, ahead)
         j = jnp.argmin(jnp.where(eff_ok, eff_rank, _IBIG))
         col = st.s[:, j] if server_specific else st.s
-        n = _argmin1d(col, st.feas[:, j])
+        n = _argmin_tie_low(col, st.feas[:, j])
         krank = eff_rank[j]
         last = krank == j_real - 1
         pidx = st.pidx + wrap.astype(jnp.int32) + last.astype(jnp.int32)
@@ -609,7 +494,7 @@ def epoch_loop_mesh(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
     """Multi-device fused epoch: the server (agent) axis sharded over a 1-D
     ``"agents"`` mesh of ``devices`` devices via ``shard_map``.  Same
     contract as :func:`epoch_loop` (padded inputs, identical grant
-    sequences), minus ``use_pallas``/``shards`` — each device IS one shard.
+    sequences) — each device holds one block of the server axis.
 
     Each device keeps its ``(N, J/devices)`` score / feasibility / residual
     block resident for the whole epoch; per grant iteration only scalar and
@@ -635,12 +520,10 @@ def epoch_loop_mesh(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
     global select is then one ``pmin`` over the (N,) row minima plus one
     scalar first-qualifying-column reduce — two collectives per grant, and
     per-grant compute drops from two full matrix passes to O(N +
-    J/devices), which is what makes the mesh path faster than the
-    single-device sharded select even without hardware parallelism.  The
-    same bookkeeping replaces the full-matrix ``any(feas)`` loop guard
-    (the select's own found flag drives liveness; the final probe
-    iteration is a no-op by predication) and RRR's per-server feasibility
-    scan with running counts.
+    J/devices).  The same bookkeeping replaces the full-matrix
+    ``any(feas)`` loop guard (the select's own found flag drives liveness;
+    the final probe iteration is a no-op by predication) and RRR's
+    per-server feasibility scan with running counts.
     """
     if policy not in ("pooled", "rrr"):
         raise ValueError(f"the mesh epoch does not cover {policy!r}")
@@ -940,8 +823,7 @@ def epoch_loop_mesh(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
               jnp.asarray(eps, f32))
 
 
-_STATIC = ("kind", "policy", "lookahead", "use_limit", "use_pallas",
-           "interpret", "max_steps", "shards")
+_STATIC = ("kind", "policy", "lookahead", "use_limit", "max_steps")
 _STATIC_MESH = ("kind", "policy", "lookahead", "use_limit", "max_steps",
                 "devices")
 
@@ -1123,15 +1005,12 @@ class _EpochRun:
     readback deferred).  ``_finish`` drives RRR grow-and-replay rounds and
     chained overflow segments exactly like the old synchronous loop did."""
 
-    def __init__(self, *, fn, kind, policy, lookahead, use_limit, use_pallas,
-                 interpret, shards, J, shape, limit, eps, draw, consts,
-                 perms, bound, max_steps_cap, snap, donate=False,
-                 devices=1):
+    def __init__(self, *, fn, kind, policy, lookahead, use_limit, J, shape,
+                 limit, eps, draw, consts, perms, bound, max_steps_cap, snap,
+                 donate=False, devices=1):
         self.fn = fn                # _jitted(donate) / _jitted_mesh()
         self.kind, self.policy = kind, policy
         self.lookahead, self.use_limit = lookahead, use_limit
-        self.use_pallas, self.interpret = use_pallas, interpret
-        self.shards = shards
         self.devices = devices      # >1: mesh dispatch (epoch_loop_mesh)
         self.donate = donate
         self.J, self.limit, self.eps = J, limit, eps
@@ -1179,9 +1058,6 @@ class _EpochRun:
                       max_steps=self.max_steps)
         if self.devices > 1:
             static["devices"] = self.devices
-        else:
-            static.update(use_pallas=self.use_pallas,
-                          interpret=self.interpret, shards=self.shards)
         self.pending = _executable(self.fn, args, static)(*args)
 
     def _wait(self):
@@ -1272,8 +1148,7 @@ def run_epoch_async(criterion, policy: str, *, X, D, C, FREE, phi, allowed,
                     per_agent_limit: Optional[int] = None,
                     lookahead: bool = False,
                     rng: Optional[np.random.Generator] = None,
-                    eps: float = 1e-9, use_pallas: bool = False,
-                    shards: int = 1, devices: int = 1,
+                    eps: float = 1e-9, devices: int = 1,
                     max_steps_cap: int = 16384,
                     preperms: Optional[np.ndarray] = None,
                     bf_metric: str = "cosine",
@@ -1290,16 +1165,10 @@ def run_epoch_async(criterion, policy: str, *, X, D, C, FREE, phi, allowed,
     ``max_steps_cap``) and RRR grow-and-replay rounds, and returns the
     grant sequence — bit-for-bit the sequence :func:`run_epoch` returns.
 
-    ``shards > 1`` partitions the in-loop selects (see the module
-    docstring); it is rounded down to a power of two dividing the padded
-    shapes.  ``devices > 1`` dispatches :func:`epoch_loop_mesh` instead —
-    the server axis sharded over that many REAL devices (rounded down to a
-    power of two; more devices than the process has is a ``ValueError``;
-    ``shards``/``use_pallas`` do not apply there, each device is one
-    resident shard).  ``use_pallas``
-    is strictly opt-in (exact-tie caveat in the module docstring);
-    ``use_pallas="persistent"`` runs the whole epoch as one persistent
-    Pallas kernel instance (``repro.kernels.epoch_persistent``).
+    ``devices > 1`` dispatches :func:`epoch_loop_mesh` instead of
+    :func:`epoch_loop` — the server axis sharded over that many REAL
+    devices (rounded down to a power of two; more devices than the process
+    has is a ``ValueError``).
     ``_donate`` forces buffer donation on/off (test hook; default: donate
     on non-CPU single-device dispatches — safe for RRR because replay
     re-uploads from a host snapshot; without donation the replay keeps
@@ -1310,7 +1179,7 @@ def run_epoch_async(criterion, policy: str, *, X, D, C, FREE, phi, allowed,
     then draws nothing up front, only grow-and-replay top-ups — total
     stream consumption is identical to letting the dispatch draw.
     ``policy="bestfit"`` (``bf_metric`` ``"cosine"``, a global criterion,
-    one device, no shards, no Pallas) first checks that ``FREE`` and the
+    one device) first checks that ``FREE`` and the
     demands are whole units the exact key can order
     (:func:`check_bestfit_inputs`) and raises where they are not.
     """
@@ -1320,25 +1189,16 @@ def run_epoch_async(criterion, policy: str, *, X, D, C, FREE, phi, allowed,
         raise ValueError(f"fused epoch does not cover {kind}/{policy}")
     if policy == "bestfit":
         if not supports(crit, policy, "characterized", "low",
-                        bf_metric=bf_metric, shards=int(shards),
-                        devices=int(devices), use_pallas=use_pallas):
+                        bf_metric=bf_metric, devices=int(devices)):
             raise ValueError(
                 f"fused best-fit covers {'/'.join(BESTFIT_CRITERIA)} with "
-                f"the {'/'.join(BESTFIT_METRICS)} metric on one device, "
-                f"without shards or Pallas; not {kind}/{bf_metric} with "
-                f"shards={shards}, devices={devices}, "
-                f"use_pallas={use_pallas!r}")
-    interpret = jax.default_backend() == "cpu"
+                f"the {'/'.join(BESTFIT_METRICS)} metric on one device; "
+                f"not {kind}/{bf_metric} with devices={devices}")
     if int(devices) > len(jax.devices()):
         raise ValueError(f"epoch asks for a {devices}-device mesh; this "
                          f"process has {len(jax.devices())} devices")
     devices = max(1, int(devices))
     devices = 1 << (devices.bit_length() - 1)    # floor to a power of two
-    if devices > 1:
-        shards = 1          # each mesh device IS one resident shard
-        use_pallas = False  # mesh body keeps jnp partials (see docstring)
-    if use_pallas == "persistent":
-        shards = 1          # one resident instance owns the whole epoch
     donate = (jax.default_backend() != "cpu" and devices <= 1) \
         if _donate is None else bool(_donate)
 
@@ -1364,9 +1224,6 @@ def run_epoch_async(criterion, policy: str, *, X, D, C, FREE, phi, allowed,
         Np, Jp = _bucket(N), _bucket(J)
         limit = np.int32(0 if per_agent_limit is None else per_agent_limit)
         use_limit = per_agent_limit is not None
-        shards = max(1, int(shards))
-        shards = 1 << (shards.bit_length() - 1)      # floor to a power of two
-        shards = min(shards, Np, Jp)                 # pow2s: divides both
         devices = min(devices, Jp)                   # pow2s: divides Jp
 
         Dp = _pad(D, Np, 0, 0.0)
@@ -1421,8 +1278,7 @@ def run_epoch_async(criterion, policy: str, *, X, D, C, FREE, phi, allowed,
                   _stage_allowed(allowed, Np, Jp))
         run = _EpochRun(
             fn=fn, kind=kind, policy=policy, lookahead=lookahead,
-            use_limit=use_limit, use_pallas=use_pallas, interpret=interpret,
-            shards=shards, devices=devices, J=J, shape=(Np, Jp),
+            use_limit=use_limit, devices=devices, J=J, shape=(Np, Jp),
             limit=limit, eps=eps,
             draw=_draw_perms, consts=consts, perms=perms, bound=bound,
             max_steps_cap=max_steps_cap, donate=donate,

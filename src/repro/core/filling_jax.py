@@ -72,7 +72,7 @@ def _masked_argmin(scores, mask, key, random_tie: bool):
 
 @functools.partial(
     jax.jit, static_argnames=("criterion", "policy", "lookahead", "tie",
-                              "max_steps", "shards", "devices")
+                              "max_steps", "devices")
 )
 def progressive_fill_jax(
     D: jax.Array,            # (N, R) demands
@@ -85,19 +85,17 @@ def progressive_fill_jax(
     lookahead: bool = False,
     tie: str = "low",
     max_steps: int = 4096,
-    shards: int = 1,         # shard the delegated epoch-loop selects
     devices: int = 1,        # shard the delegated epoch over a device mesh
     x0: jax.Array | None = None,
     allowed: jax.Array | None = None,   # (N, J) bool placement constraints
 ) -> jax.Array:
     """Run progressive filling; returns the (N, J) int32 allocation.
 
-    ``shards > 1`` partitions the deterministic pooled path's in-loop
-    selects across agent shards; ``devices > 1`` delegates to the
+    ``devices > 1`` delegates the deterministic pooled path to the
     device-mesh epoch (``engine_jax.epoch_loop_mesh`` — J must divide by
-    the mesh size) instead.  Both are parity-gated (see the engine_jax
-    module docstring); the legacy RRR/bestfit/random-tie bodies ignore
-    them."""
+    the mesh size), parity-gated against the single-device loop (see the
+    engine_jax module docstring); the legacy RRR/bestfit/random-tie bodies
+    ignore it."""
     crit = criteria.get_criterion(criterion)
     pol = _POL[policy]
     random_tie = tie == "random"
@@ -139,8 +137,7 @@ def progressive_fill_jax(
         else:
             _ns, _js, _cnt, x_fin, *_rest = engine_jax.epoch_loop(
                 *loop_args, kind=crit.name, policy=policy,
-                lookahead=lookahead, use_limit=False, use_pallas=False,
-                interpret=False, max_steps=max_steps, shards=shards,
+                lookahead=lookahead, use_limit=False, max_steps=max_steps,
             )
         return x_fin.astype(jnp.int32)
 

@@ -87,7 +87,6 @@ from repro.core.engine import (
     AUTO_KERNEL_FLOOR_CELLS,
     AUTO_KERNEL_MIN_CELLS,
     AUTO_MESH_MIN_CELLS,
-    AUTO_SHARD_MIN_CELLS,
     BatchedEpoch,
 )
 
@@ -175,8 +174,8 @@ class InFlightEpoch:
     #   exactly where it would be had the epoch never begun (and a host
     #   re-run of a failed fused epoch draws the identical sequence).
     tie: str = "low"                    # epoch knobs kept for recovery
-    shards: int = 1                     #   re-dispatch (commit-time retry
-    devices: int = 1                    #   of a failed device readback).
+    devices: int = 1                    #   re-dispatch (commit-time retry
+    #   of a failed device readback).
 
     @property
     def in_flight(self) -> bool:
@@ -896,7 +895,7 @@ class OnlineAllocator:
 
     def allocate_batched(self, per_agent_limit: Optional[int] = None,
                          tie: str = "low", use_kernel="auto",
-                         shards: int = 1, devices: int = 1) -> list[Grant]:
+                         devices: int = 1) -> list[Grant]:
         """Batched epoch: score once, grant many (see module docstring).
 
         ``use_kernel`` selects the backend:
@@ -917,7 +916,7 @@ class OnlineAllocator:
             Covers characterized mode, ``tie="low"``, every criterion under
             the pooled/rrr policies (phi, constraints, per_agent_limit
             included) and DRF/TSF under best-fit with the cosine metric on
-            one device and one shard; anything else silently falls back to
+            one device; anything else silently falls back to
             the numpy incremental path.  Fused best-fit raises (before any
             dispatch) on free resources or demands that are not whole
             units (``engine_jax.check_bestfit_inputs``).  Fused RRR
@@ -929,25 +928,22 @@ class OnlineAllocator:
             rPS-DSF + pooled only), kept for benchmarking the boundary cost.
           * ``False`` — pure numpy incremental epoch.
 
-        ``shards > 1`` partitions the fused epoch's in-loop selects across
-        agent shards; ``devices > 1`` shards the epoch state itself over a
-        device mesh (``engine_jax.epoch_loop_mesh`` — each device keeps its
-        agent-block resident, only reduce partials cross the interconnect).
-        Both are parity-gated (see the engine_jax module docstring), and
-        under ``"auto"`` both collapse to the plain fused dispatch below
-        their measured floors (:meth:`_resolve_partition`).
+        ``devices > 1`` partitions the epoch state over a device mesh
+        (``engine_jax.epoch_loop_mesh`` — each device keeps its agent-block
+        resident, only reduce partials cross the interconnect), parity-gated
+        against the single-device loop; under ``"auto"`` it collapses to the
+        single-device dispatch below its floor (:meth:`_resolve_devices`).
 
         Implemented as ``commit_epoch(begin_epoch(...))`` — the synchronous
         path and the asynchronous pipeline are the same code.
         """
         return self.commit_epoch(self.begin_epoch(
-            per_agent_limit, tie=tie, use_kernel=use_kernel, shards=shards,
-            devices=devices))
+            per_agent_limit, tie=tie, use_kernel=use_kernel, devices=devices))
 
     # -- the asynchronous epoch pipeline -------------------------------------
 
     def _resolve_kernel(self, use_kernel, N: int, J: int, tie: str,
-                        shards: int = 1, devices: int = 1):
+                        devices: int = 1):
         """Resolve a ``use_kernel`` spec to ``False | "pergrant" | "fused"``."""
         if use_kernel in (False, None):
             return False
@@ -958,8 +954,7 @@ class OnlineAllocator:
 
             return "fused" if engine_jax.supports(
                 self.crit, self.server_policy, self.mode, tie,
-                bf_metric=self.bf_metric, shards=shards,
-                devices=devices) else False
+                bf_metric=self.bf_metric, devices=devices) else False
         if use_kernel == "auto":
             if N * J < AUTO_KERNEL_FLOOR_CELLS:
                 return False        # small epoch: never pay the jax import
@@ -1103,17 +1098,14 @@ class OnlineAllocator:
             grants.append(self._grant(view.fids[n], view.agents[j]))
         return grants
 
-    def _resolve_partition(self, use_kernel, N: int, J: int, shards: int,
-                           devices: int):
-        """Clamp a requested fused-epoch partitioning under ``"auto"``.
+    def _resolve_devices(self, use_kernel, N: int, J: int, devices: int):
+        """Clamp a requested device-mesh size under ``"auto"``.
 
-        Sharded selects and device-mesh epochs each pay a fixed per-grant
-        toll that only amortizes near fleet scale, so the auto rule honors
-        ``shards``/``devices`` requests only at or above their measured
-        floors (:data:`repro.core.engine.AUTO_SHARD_MIN_CELLS` /
-        :data:`~repro.core.engine.AUTO_MESH_MIN_CELLS`) and collapses them
-        to the plain fused dispatch below; it also fits a mesh request to
-        the devices the process has.  Explicit ``use_kernel`` specs are a
+        A mesh epoch pays a per-grant collective toll that only amortizes
+        near fleet scale, so the auto rule honors a ``devices`` request only
+        at or above :data:`repro.core.engine.AUTO_MESH_MIN_CELLS` and
+        collapses it to one device below; it also fits the request to the
+        devices the process has.  Explicit ``use_kernel`` specs are a
         stated choice and pass through untouched (the engine refuses a mesh
         larger than the process) — EXCEPT while the
         device path is quarantined (see :class:`~repro.core.faults
@@ -1122,23 +1114,18 @@ class OnlineAllocator:
         """
         if self.device_health.quarantined and devices > 1:
             devices = 1
-        if use_kernel != "auto":
-            return shards, devices
-        cells = N * J
-        if shards > 1 and cells < AUTO_SHARD_MIN_CELLS:
-            shards = 1
-        if devices > 1 and cells < AUTO_MESH_MIN_CELLS:
-            devices = 1
-        if devices > 1:
-            import jax
+        if use_kernel != "auto" or devices <= 1:
+            return devices
+        if N * J < AUTO_MESH_MIN_CELLS:
+            return 1
+        import jax
 
-            devices = min(devices, len(jax.devices()))
-        return shards, devices
+        return min(devices, len(jax.devices()))
 
     @_tracing.traced("online.begin_epoch")
     def begin_epoch(self, per_agent_limit: Optional[int] = None,
                     tie: str = "low", use_kernel="auto",
-                    shards: int = 1, devices: int = 1) -> InFlightEpoch:
+                    devices: int = 1) -> InFlightEpoch:
         """Stage one epoch and dispatch it without blocking on the result.
 
         Freezes the epoch inputs (X/D/C/FREE/phi/allowed/wanted + the true
@@ -1188,7 +1175,7 @@ class OnlineAllocator:
                 TD[i] = self._true_demand(f)
         TD.setflags(write=False)
         kernel = self._resolve_kernel(use_kernel, N, len(view.agents), tie,
-                                      shards, devices)
+                                      devices)
         # bracket opens at kernel resolution: every rng draw (fused preperm
         # prefix, host per-round permutations) lands inside it, and a crash
         # before the matching commit/abort record recovers by rewinding to
@@ -1243,11 +1230,11 @@ class OnlineAllocator:
                                      revocations=revs)
 
         if kernel == "fused":
-            shards, devices = self._resolve_partition(
-                use_kernel, N, len(view.agents), shards, devices)
+            devices = self._resolve_devices(use_kernel, N, len(view.agents),
+                                            devices)
             try:
                 handle = self._dispatch_fused(view, TD, per_agent_limit,
-                                              shards, devices, preperms)
+                                              devices, preperms)
             except Exception:
                 # not a device fault (see faults.is_device_fault): surface
                 # it with the epoch undone — stream rewound, bracket closed
@@ -1262,7 +1249,7 @@ class OnlineAllocator:
                                       revocations=revs, cache_key=key,
                                       perm_rows0=nperm0,
                                       rng_state0=rng_state0, tie=tie,
-                                      shards=shards, devices=devices)
+                                      devices=devices)
                 self._inflight_epoch = epoch
                 return epoch
             # device path down (retries exhausted): self-heal on the host
@@ -1341,8 +1328,7 @@ class OnlineAllocator:
 
     # -- self-healing dispatch (core.faults) ---------------------------------
 
-    def _dispatch_fused(self, view, TD, per_agent_limit, shards, devices,
-                        preperms):
+    def _dispatch_fused(self, view, TD, per_agent_limit, devices, preperms):
         """Dispatch the fused device epoch, retrying device faults with
         capped exponential backoff (:class:`~repro.core.faults
         .RecoveryPolicy`).  Returns the :class:`EpochHandle`, or ``None``
@@ -1369,8 +1355,8 @@ class OnlineAllocator:
                     X=view.X, D=view.D, C=view.C, FREE=view.FREE,
                     phi=view.phi, allowed=view.allowed, wanted=view.wanted,
                     true_demands=TD, per_agent_limit=per_agent_limit,
-                    lookahead=False, rng=self.rng, shards=shards,
-                    devices=devices, preperms=preperms,
+                    lookahead=False, rng=self.rng, devices=devices,
+                    preperms=preperms,
                     bf_metric=self.bf_metric,
                 )
             except Exception as exc:
@@ -1429,8 +1415,8 @@ class OnlineAllocator:
             X=view.X, D=view.D, C=view.C, FREE=view.FREE,
             phi=view.phi, allowed=view.allowed, wanted=view.wanted,
             true_demands=epoch.TD, per_agent_limit=epoch.per_agent_limit,
-            lookahead=False, rng=self.rng, shards=epoch.shards,
-            devices=epoch.devices, preperms=None, bf_metric=self.bf_metric,
+            lookahead=False, rng=self.rng, devices=epoch.devices,
+            preperms=None, bf_metric=self.bf_metric,
         )
 
     def _recover_commit(self, epoch: InFlightEpoch, exc) -> list[Grant]:

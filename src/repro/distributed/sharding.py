@@ -143,7 +143,7 @@ def constrain(x, logical_axes: tuple, override: Optional[dict] = None):
 
 
 def weight_gather(w, logical_axes: tuple):
-    """Weight-gather FSDP: force the FSDP ("embed"-over-data) shards of a
+    """Weight-gather FSDP: force the FSDP ("embed"-over-data) shard blocks of a
     weight to all-gather BEFORE use, keeping TP axes intact.  Without this,
     GSPMD tends to keep weights sharded and psum the (much larger) activation
     partial sums — measured 2.6e12 B/step of all-reduce on deepseek train_4k

@@ -7,7 +7,7 @@ microtasking, pull-based executors, and speculative re-execution at
 barriers.  The analogous training-fleet mechanisms implemented here:
 
   * microtasking        -> micro-batch grad accumulation (train/steps.py)
-  * executor pull       -> per-host data shards pulled from a deterministic
+  * executor pull       -> per-host data slices pulled from a deterministic
                            stream (data/pipeline.py) — any host can take over
                            any row range after a rescale
   * speculative exec    -> StragglerMonitor: per-host step-time EMA; hosts

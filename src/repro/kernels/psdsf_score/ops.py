@@ -6,7 +6,10 @@ run the Pallas kernel (interpret=True on CPU), reduce tile partials.
   * :func:`masked_argmin1d` — masked argmin over a score vector (an RRR
     server visit, or DRF/TSF scores against row feasibility);
   * :func:`masked_argmin2d` — masked argmin over a maintained (N, J) score
-    matrix (pooled selection in the incremental device epoch).
+    matrix (a pooled selection).
+
+Only :func:`psdsf_argmin` has a caller (the per-grant kernel backend of
+``repro.core.engine``); the fused device epoch reduces with jnp.
 """
 from __future__ import annotations
 

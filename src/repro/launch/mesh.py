@@ -35,7 +35,7 @@ def make_smoke_mesh():
 def make_agent_mesh(n: int):
     """1-D mesh over the first ``n`` local devices, axis name ``"agents"``.
 
-    The fused allocation epoch shards the server (Mesos agent) axis over
+    The fused allocation epoch splits the server (Mesos agent) axis over
     this mesh (see ``repro.core.engine_jax.epoch_loop_mesh``): each device
     owns a contiguous block of server columns and only (min, argmin)
     partials cross the interconnect per grant iteration.  ``n`` may be

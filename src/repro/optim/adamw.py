@@ -1,5 +1,5 @@
 """AdamW with decoupled weight decay, fp32 moments, cosine schedule, global
-gradient clipping — pure-JAX (no optax).  Optimizer state shards exactly like
+gradient clipping — pure-JAX (no optax).  Optimizer state is sharded exactly like
 the parameters (ZeRO: the FSDP rules apply to m/v too)."""
 from __future__ import annotations
 

@@ -1,5 +1,5 @@
 """Async epoch pipeline: begin/commit double-buffering, deterministic
-simulator commit points, the sharded device-epoch select, and the
+simulator commit points, the ``auto`` device-mesh floor, and the
 donation-safe RRR replay path.
 
 Parity contracts pinned here:
@@ -13,10 +13,12 @@ Parity contracts pinned here:
     grant log) on the golden scenario grid for seeds 0-2: the commit point
     (before the next processed event, at the dispatching epoch's simulated
     time) is deterministic by construction;
-  * sharded select — ``shards=K`` epochs equal the unsharded loop, and a
-    new shard count costs AT MOST one retrace per shape bucket;
+  * ``auto`` mesh floor — a ``devices`` request below
+    ``AUTO_MESH_MIN_CELLS`` collapses to one device, and above it fits the
+    process's devices; explicit specs pass through;
   * donation-safe RRR — forced-donation grow-and-replay re-uploads from
-    the host snapshot and still reproduces the numpy sequence.
+    the host snapshot and still reproduces the numpy sequence, for every
+    criterion.
 """
 import warnings
 
@@ -57,7 +59,7 @@ def _instances():
 
 
 def _fill(inst, criterion, policy, seed, *, mode="sync", use_kernel=False,
-          shards=1):
+          devices=1):
     """Drive one epoch over an Instance through the chosen path; returns
     the (fid, agent) grant order."""
     al = OnlineAllocator(inst.n_resources, criterion=criterion,
@@ -73,10 +75,10 @@ def _fill(inst, criterion, policy, seed, *, mode="sync", use_kernel=False,
         al.register(f"f{n:03d}", demand=inst.demands[n], wanted_tasks=10**6,
                     phi=inst.weights[n], allowed_agents=allowed)
     if mode == "async":
-        epoch = al.begin_epoch(use_kernel=use_kernel, shards=shards)
+        epoch = al.begin_epoch(use_kernel=use_kernel, devices=devices)
         grants = al.commit_epoch(epoch)
     else:
-        grants = al.allocate_batched(use_kernel=use_kernel, shards=shards)
+        grants = al.allocate_batched(use_kernel=use_kernel, devices=devices)
     return [(g.fid, g.agent) for g in grants]
 
 
@@ -101,14 +103,14 @@ def test_begin_commit_matches_numpy_batched(crit, pol):
 def test_begin_commit_host_fallback_matches_sync():
     """Configurations outside device coverage flow through the SAME
     begin/commit API (host fallback at begin time) with identical grants:
-    best-fit after a server-specific criterion, and BF-DRF over shards
-    (the fused best-fit runs on one shard only)."""
+    best-fit after a server-specific criterion, and BF-DRF over a device
+    mesh (the fused best-fit runs on one device only)."""
     inst = spark_cluster_heterogeneous()
-    for crit, pol, shards in (("rpsdsf", "bestfit", 1),
-                              ("drf", "bestfit", 2)):
+    for crit, pol, devices in (("rpsdsf", "bestfit", 1),
+                               ("drf", "bestfit", 2)):
         ref = _fill(inst, crit, pol, 0, mode="sync", use_kernel=False)
         got = _fill(inst, crit, pol, 0, mode="async", use_kernel="fused",
-                    shards=shards)
+                    devices=devices)
         assert ref == got, f"{crit}/{pol}"
 
 
@@ -259,115 +261,33 @@ def test_async_auto_kernel_runs_to_completion():
 
 
 # ---------------------------------------------------------------------------
-# sharded device-epoch select
+# the auto device-mesh floor
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("crit", CRITERIA)
-@pytest.mark.parametrize("pol", DEVICE_POLICIES)
-def test_sharded_epoch_matches_unsharded(crit, pol):
-    """shards=K partitions the in-loop selects; grant sequences equal the
-    unsharded loop AND the numpy engine on every instance."""
-    pytest.importorskip("jax")
-    for name, inst in _instances().items():
-        ref = _fill(inst, crit, pol, 0, mode="sync", use_kernel=False)
-        for shards in (2, 4):
-            got = _fill(inst, crit, pol, 0, mode="sync", use_kernel="fused",
-                        shards=shards)
-            assert ref == got, f"{name}/shards={shards}"
-
-
-def test_sharded_trace_count_regression():
-    """A new shard count retraces AT MOST once per shape bucket; repeats at
-    the same (bucket, shards) reuse the cached executable."""
-    pytest.importorskip("jax")
-    from repro.core import engine_jax
-
-    inst = spark_cluster_heterogeneous()
-
-    def run(shards, seed=0):
-        return _fill(inst, "rpsdsf", "pooled", seed, mode="sync",
-                     use_kernel="fused", shards=shards)
-
-    run(2)                                   # enter the (bucket, 2) cache
-    t0 = engine_jax.TRACE_COUNT
-    run(2, seed=1)                           # same bucket + shards: cached
-    assert engine_jax.TRACE_COUNT == t0
-    run(4)                                   # new shard count: <= 1 trace
-    assert engine_jax.TRACE_COUNT <= t0 + 1
-    run(4, seed=1)
-    assert engine_jax.TRACE_COUNT <= t0 + 1
-
-
-@pytest.mark.parametrize("pol", DEVICE_POLICIES)
-def test_sharded_wanted_exhaustion_and_limit(pol):
-    """Mid-epoch ``wanted`` exhaustion + ``per_agent_limit`` under
-    shards>1: the sharded loop stops at the reference count and never
-    exceeds the per-agent cap."""
-    pytest.importorskip("jax")
-    from repro.core import engine_jax
-
-    rng = np.random.default_rng(5)
-    N, J, R = 7, 6, 2
-    D = rng.uniform(0.5, 1.5, (N, R))
-    C = rng.uniform(6.0, 12.0, (J, R))
-    kw = dict(X=np.zeros((N, J)), D=D, C=C, FREE=C.copy(),
-              phi=rng.uniform(0.5, 2.0, N),
-              wanted=rng.integers(1, 3, N).astype(float),  # exhausts early
-              allowed=rng.random((N, J)) > 0.2, true_demands=D,
-              per_agent_limit=2)
-    ref = engine_jax.run_epoch("rpsdsf", pol,
-                               rng=np.random.default_rng(1), **kw)
-    got = engine_jax.run_epoch("rpsdsf", pol,
-                               rng=np.random.default_rng(1), shards=2, **kw)
-    assert ref == got
-    assert 0 < len(ref) < int(kw["wanted"].sum()) + 1
-    counts = np.bincount([j for _n, j in ref])
-    assert counts.max() <= 2
-
-
 def test_auto_partition_floors_clamp_small_epochs():
-    """use_kernel='auto' collapses shards/devices requests below the
-    measured floors to the plain fused dispatch, and fits a mesh above
-    them to the process's devices; explicit specs pass through untouched."""
+    """use_kernel='auto' collapses a devices request below the measured
+    floor to one device, and fits a mesh above it to the process's
+    devices; explicit specs pass through untouched."""
     jax = pytest.importorskip("jax")
-    from repro.core.engine import AUTO_MESH_MIN_CELLS, AUTO_SHARD_MIN_CELLS
+    from repro.core.engine import AUTO_MESH_MIN_CELLS
 
     al = OnlineAllocator(2, criterion="drf", server_policy="pooled", seed=0)
-    assert al._resolve_partition("auto", 50, 25, 8, 8) == (1, 1)
-    big_n = AUTO_SHARD_MIN_CELLS // 1024 + 1
-    assert al._resolve_partition("auto", big_n, 1024, 8, 1) == (8, 1)
+    assert al._resolve_devices("auto", 50, 25, 8) == 1
+    assert al._resolve_devices("auto", 50, 25, 1) == 1
     big_n = AUTO_MESH_MIN_CELLS // 1024 + 1
-    assert al._resolve_partition("auto", big_n, 1024, 1, 8) == (
-        1, min(8, len(jax.devices())))
-    assert al._resolve_partition("fused", 50, 25, 8, 8) == (8, 8)
-    assert al._resolve_partition(True, 50, 25, 4, 2) == (4, 2)
-
-
-def test_progressive_fill_jax_sharded_parity():
-    """The delegated filling_jax pooled path accepts shards and keeps its
-    allocation unchanged."""
-    jax = pytest.importorskip("jax")
-    import jax.numpy as jnp
-
-    from repro.core.filling_jax import progressive_fill_jax
-
-    inst = spark_cluster_heterogeneous()
-    args = (jnp.asarray(inst.demands, jnp.float32),
-            jnp.asarray(inst.capacities, jnp.float32),
-            jnp.asarray(inst.weights, jnp.float32))
-    base = progressive_fill_jax(*args, jax.random.key(0), criterion="psdsf",
-                                policy="pooled", tie="low")
-    sharded = progressive_fill_jax(*args, jax.random.key(0),
-                                   criterion="psdsf", policy="pooled",
-                                   tie="low", shards=2)
-    np.testing.assert_array_equal(np.asarray(base), np.asarray(sharded))
+    assert al._resolve_devices("auto", big_n, 1024, 8) == min(
+        8, len(jax.devices()))
+    assert al._resolve_devices("auto", big_n, 1024, 1) == 1
+    assert al._resolve_devices("fused", 50, 25, 8) == 8
+    assert al._resolve_devices(True, 50, 25, 2) == 2
 
 
 # ---------------------------------------------------------------------------
 # donation-safe RRR
 # ---------------------------------------------------------------------------
 
-def test_rrr_forced_donation_replay_and_chaining_parity():
+@pytest.mark.parametrize("crit", CRITERIA)
+def test_rrr_forced_donation_replay_and_chaining_parity(crit):
     """With donation FORCED on (the non-CPU default), the RRR
     grow-and-replay path re-uploads the segment state from the host
     snapshot; grant sequences still equal the numpy engine, including
@@ -376,7 +296,7 @@ def test_rrr_forced_donation_replay_and_chaining_parity():
     from repro.core import engine_jax
 
     inst = spark_cluster_heterogeneous()
-    ref = _fill(inst, "rpsdsf", "rrr", 1, mode="sync", use_kernel=False)
+    ref = _fill(inst, crit, "rrr", 1, mode="sync", use_kernel=False)
 
     def fused(**kw):
         with warnings.catch_warnings():
@@ -384,7 +304,7 @@ def test_rrr_forced_donation_replay_and_chaining_parity():
             # path under test (snapshot re-upload) runs regardless
             warnings.simplefilter("ignore")
             return engine_jax.run_epoch(
-                "rpsdsf", "rrr", X=np.zeros((2, 6)), D=inst.demands,
+                crit, "rrr", X=np.zeros((2, 6)), D=inst.demands,
                 C=inst.capacities, FREE=inst.capacities.copy(),
                 phi=inst.weights, allowed=inst.allowed,
                 wanted=np.full(2, 10.0**6), true_demands=inst.demands,

@@ -249,8 +249,8 @@ def test_mesh_request_beyond_the_process_is_refused_unless_auto():
     with pytest.raises(ValueError, match="device mesh"):
         al.allocate_batched(use_kernel="fused", devices=too_many)
     assert al.fault_counters()["host_fallbacks"] == 0
-    assert al._resolve_partition("auto", 2048, 1024, 1, too_many) == (
-        1, len(jax.devices()))
+    assert al._resolve_devices("auto", 2048, 1024, too_many) == len(
+        jax.devices())
 
 
 def test_fault_free_injector_is_a_noop():
@@ -363,14 +363,14 @@ def test_quarantine_gates_auto_resolution(monkeypatch):
 
 def test_quarantine_degrades_mesh_to_single_device():
     al = build_alloc("pooled")
-    assert al._resolve_partition("fused", 4, 6, 1, 4) == (1, 4)
+    assert al._resolve_devices("fused", 4, 6, 4) == 4
     al.device_health.on_failure()
     al.device_health.on_failure()
     al.device_health.on_failure()
     assert al.device_health.quarantined
-    assert al._resolve_partition("fused", 4, 6, 1, 4) == (1, 1)
+    assert al._resolve_devices("fused", 4, 6, 4) == 1
     al.device_health.on_success()
-    assert al._resolve_partition("fused", 4, 6, 1, 4) == (1, 4)
+    assert al._resolve_devices("fused", 4, 6, 4) == 4
 
 
 # ---------------------------------------------------------------------------

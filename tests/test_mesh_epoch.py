@@ -1,20 +1,18 @@
-"""Device-mesh allocation epochs (shard_map) and the persistent
-whole-epoch Pallas kernel.
+"""Device-mesh allocation epochs (shard_map): the one partitioned epoch
+path.
 
 Parity contracts pinned here:
 
-  * mesh == unsharded — ``epoch_loop_mesh`` grant sequences AND final
+  * mesh == single device — ``epoch_loop_mesh`` grant sequences AND final
     state arrays equal the fused single-device loop bit-for-bit for every
-    covered criterion x policy combo (1-device mesh in-process; a true
-    8-forced-host-device mesh in a subprocess, including the allocator's
-    async begin/commit path and the RRR grow-and-replay);
+    covered criterion x policy combo (1-device mesh in-process; true 2-
+    and 8-device meshes over forced host devices in a subprocess,
+    including the allocator's async begin/commit path and chained RRR
+    segments with grow-and-replay), one test case per check;
   * mid-epoch exhaustion — small ``wanted`` budgets and
     ``per_agent_limit`` stop the mesh loop at exactly the reference grant
-    count (the select's found-flag liveness, not the old full-matrix
-    ``any(feas)`` guard);
-  * persistent kernel — ``use_pallas="persistent"`` (the whole epoch as
-    ONE ``pallas_call`` instance) equals the fused loop on every covered
-    combo;
+    count (the select's found-flag liveness, not the single-device loop's
+    full-matrix ``any(feas)`` guard);
   * retrace discipline — a mesh (shape, devices) key retraces at most
     once; repeats reuse the cached executable.
 """
@@ -76,8 +74,7 @@ def test_mesh_epoch_matches_fused(crit, pol):
     args = _raw_epoch_inputs(kw, limit)
     ref = ej._jitted(False)(
         *args, kind=crit, policy=pol, lookahead=False,
-        use_limit=limit is not None, use_pallas=False, interpret=False,
-        max_steps=64, shards=1)
+        use_limit=limit is not None, max_steps=64)
     got = ej._jitted_mesh()(
         *args, kind=crit, policy=pol, lookahead=False,
         use_limit=limit is not None, max_steps=64, devices=1)
@@ -98,7 +95,7 @@ def test_mesh_wanted_exhaustion_and_limit(pol):
     args = _raw_epoch_inputs(kw, 2, max_steps=64)
     ref = ej._jitted(False)(
         *args, kind="rpsdsf", policy=pol, lookahead=False, use_limit=True,
-        use_pallas=False, interpret=False, max_steps=64, shards=1)
+        max_steps=64)
     got = ej._jitted_mesh()(
         *args, kind="rpsdsf", policy=pol, lookahead=False, use_limit=True,
         max_steps=64, devices=1)
@@ -133,26 +130,6 @@ def test_mesh_trace_count_regression():
     assert ej.MESH_TRACE_COUNT <= t0 + 1
 
 
-@pytest.mark.parametrize("crit,pol,limit", [
-    ("drf", "pooled", None), ("tsf", "rrr", None),
-    ("psdsf", "rrr", 3), ("rpsdsf", "pooled", 3), ("rpsdsf", "rrr", None),
-])
-def test_persistent_epoch_matches_fused(crit, pol, limit):
-    """The whole-epoch persistent Pallas kernel (interpreter mode on CPU)
-    reproduces the fused loop's grant sequence exactly."""
-    pytest.importorskip("jax")
-    from repro.core.engine_jax import run_epoch_async
-
-    kw = _epoch_args(seed=hash((crit, pol, str(limit))) % 2**31)
-    ref = run_epoch_async(crit, pol, rng=np.random.default_rng(2),
-                          per_agent_limit=limit, **kw).result()
-    got = run_epoch_async(crit, pol, rng=np.random.default_rng(2),
-                          per_agent_limit=limit, use_pallas="persistent",
-                          **kw).result()
-    assert ref == got
-    assert len(ref) > 0
-
-
 _MESH8_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -173,21 +150,21 @@ _MESH8_SCRIPT = textwrap.dedent("""
                     wanted=rng.integers(1, 6, N).astype(float),
                     allowed=rng.random((N, J)) > 0.2, true_demands=TD)
 
-    fails = 0
+    def report(name, ok, detail):
+        print("CHECK", name, "OK" if ok else "FAIL", detail, flush=True)
+
     for kind in ["drf", "tsf", "psdsf", "rpsdsf"]:
         for policy in ["pooled", "rrr"]:
             limit = 3 if kind in ("drf", "rpsdsf") else None
             kw = inst(hash((kind, policy)) % 2**31)
-            a = run_epoch_async(kind, policy, rng=np.random.default_rng(7),
-                                per_agent_limit=limit, devices=1,
-                                **kw).result()
-            b = run_epoch_async(kind, policy, rng=np.random.default_rng(7),
-                                per_agent_limit=limit, devices=8,
-                                **kw).result()
-            ok = a == b and len(a) > 0
-            fails += 0 if ok else 1
-            print(("OK  " if ok else "FAIL"), kind, policy, limit,
-                  len(a), len(b), flush=True)
+            seqs = {k: run_epoch_async(kind, policy,
+                                       rng=np.random.default_rng(7),
+                                       per_agent_limit=limit, devices=k,
+                                       **kw).result()
+                    for k in (1, 2, 8)}
+            ok = len(seqs[1]) > 0 and seqs[1] == seqs[2] == seqs[8]
+            report(f"combo/{kind}/{policy}", ok,
+                   {k: len(v) for k, v in seqs.items()})
 
     # chained segments + RRR grow-and-replay under the mesh path
     kw = inst(99)
@@ -198,9 +175,8 @@ _MESH8_SCRIPT = textwrap.dedent("""
         b = run_epoch_async(kind, "rrr", rng=np.random.default_rng(3),
                             max_steps_cap=16, _perm_rows=2, devices=8,
                             **kw).result()
-        ok = a == b
-        fails += 0 if ok else 1
-        print(("OK  " if ok else "FAIL"), "chain+replay", kind, flush=True)
+        report(f"chain+replay/{kind}", a == b and len(a) > 16,
+               (len(a), len(b)))
 
     # allocator async begin/commit over the mesh == synchronous numpy
     def fill(crit, policy, devices, use_kernel):
@@ -218,20 +194,21 @@ _MESH8_SCRIPT = textwrap.dedent("""
     for crit, policy in [("rpsdsf", "pooled"), ("drf", "rrr")]:
         ref = fill(crit, policy, 1, False)
         got = fill(crit, policy, 8, "fused")
-        ok = ref == got and len(ref) > 0
-        fails += 0 if ok else 1
-        print(("OK  " if ok else "FAIL"), "begin/commit", crit, policy,
-              flush=True)
-
-    assert fails == 0, fails
-    print("MESH8_OK")
+        report(f"begin-commit/{crit}/{policy}", ref == got and len(ref) > 0,
+               (len(ref), len(got)))
 """)
 
+MESH8_CHECKS = (
+    [f"combo/{k}/{p}" for k in CRITERIA for p in POLICIES]
+    + [f"chain+replay/{k}" for k in ("drf", "rpsdsf")]
+    + [f"begin-commit/{c}/{p}" for c, p in (("rpsdsf", "pooled"),
+                                            ("drf", "rrr"))])
 
-def test_mesh_epoch_parity_on_8_devices():
-    """True 8-device mesh in a subprocess (the device count locks at first
-    jax init): every covered combo, chained+replayed RRR segments, and the
-    allocator's async begin/commit path equal the single-device engine."""
+
+@pytest.fixture(scope="module")
+def mesh8_run():
+    """The 8-forced-host-device subprocess, run once for every check (the
+    device count locks at first jax init): its ``CHECK`` lines by name."""
     pytest.importorskip("jax")
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
@@ -241,6 +218,22 @@ def test_mesh_epoch_parity_on_8_devices():
         capture_output=True, text=True, timeout=560, env=env,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     )
-    assert out.returncode == 0, \
-        f"stdout:\n{out.stdout[-2000:]}\nstderr:\n{out.stderr[-3000:]}"
-    assert "MESH8_OK" in out.stdout
+    checks = {}
+    for line in out.stdout.splitlines():
+        if line.startswith("CHECK "):
+            _tag, name, verdict, detail = line.split(" ", 3)
+            checks[name] = (verdict, detail)
+    return out, checks
+
+
+@pytest.mark.parametrize("check", MESH8_CHECKS)
+def test_mesh_epoch_parity_on_8_devices(mesh8_run, check):
+    """True multi-device meshes in a subprocess: each covered combo on 2
+    and 8 devices, chained+replayed RRR segments on 8, and the allocator's
+    async begin/commit path on 8 equal the single-device engine."""
+    out, checks = mesh8_run
+    assert check in checks, (
+        f"check {check} did not report (rc {out.returncode})\n"
+        f"stdout:\n{out.stdout[-2000:]}\nstderr:\n{out.stderr[-3000:]}")
+    verdict, detail = checks[check]
+    assert verdict == "OK", f"{check}: {detail}"

@@ -84,8 +84,7 @@ def test_epoch_loop_compiles_for_v5e(one_chip, kind, policy, N, J):
             else 1)
     compiled = engine_jax._jitted(True).lower(
         *_epoch_args(one_chip, N, J, rows), kind=kind, policy=policy,
-        lookahead=False, use_limit=False, use_pallas=False, interpret=False,
-        max_steps=MAX_STEPS, shards=1).compile()
+        lookahead=False, use_limit=False, max_steps=MAX_STEPS).compile()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
