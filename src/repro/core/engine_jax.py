@@ -272,7 +272,12 @@ def bestfit_argmin(u2, v, ok):
     return jax.lax.reduce((ok, u2, v, idx), init, _bestfit_better, (0,))[3]
 
 
-def _argmin_tie_low(s, mask, rtol=1e-6, atol=1e-9):
+def _tie_threshold(m, rtol=1e-6, atol=1e-9):
+    """The largest masked score that ties with the minimum ``m``."""
+    return m + (atol + rtol * jnp.abs(m))
+
+
+def _argmin_tie_low(s, mask):
     """First index among near-minimal masked entries (numpy tie="low").
 
     The tolerance covers a few f32 ULPs of rounding (~3.6e-7 relative for
@@ -282,10 +287,17 @@ def _argmin_tie_low(s, mask, rtol=1e-6, atol=1e-9):
     merged too — that is the residual f32 parity boundary documented in
     the module docstring."""
     masked = jnp.where(mask, s.astype(jnp.float32), _BIG)
-    m = jnp.min(masked)
-    tol = atol + rtol * jnp.abs(m)
     idx = jnp.arange(masked.shape[0], dtype=jnp.int32)
-    return jnp.min(jnp.where(masked <= m + tol, idx, _IBIG))
+    return jnp.min(jnp.where(masked <= _tie_threshold(jnp.min(masked)), idx,
+                             _IBIG))
+
+
+def block_width(J: int) -> int:
+    """Column width of one block of the pooled 2-D select's minima: about
+    sqrt(J), a power of two of at least 128 lanes (128 at J = 16384).  A
+    step rescans one block of every row and every block of one row, so
+    this width keeps both near sqrt(N J) entries."""
+    return max(128, 1 << (((J - 1).bit_length() + 1) // 2))
 
 
 class _EpochState(NamedTuple):
@@ -294,9 +306,14 @@ class _EpochState(NamedTuple):
     FREE: jax.Array     # (J, R) f32
     cap: jax.Array      # (J, R) f32 residuals (rpsdsf) or (1, 1) dummy
     dom: jax.Array      # (N, J) f32 dominant shares (psdsf family) or (1, 1)
-    s: jax.Array        # (N,) or (N, J) f32 criterion scores
-    feas: jax.Array     # (N, J) bool
+    s: jax.Array        # (N,) f32 criterion scores; psdsf family: the row
+    #                     factor (tot + la) / phi of pair scores s[n] dom[n, j]
+    feas: jax.Array     # (N, J) bool, exact in the rows that still want
     used: jax.Array     # (J,) i32 grants per server this epoch
+    fcnt: jax.Array     # (N,) i32 feasible columns per row
+    ccnt: Optional[jax.Array]   # (J,) i32 feasible rows per column (rrr)
+    bmin: Optional[jax.Array]   # (J/B, N) f32 least feasible dominant share
+    #                             per block and row (pooled psdsf family)
     pidx: jax.Array     # () i32 RRR permutation cursor
     pos: jax.Array      # () i32 RRR position within the round
     count: jax.Array    # () i32 grants so far
@@ -308,7 +325,8 @@ def epoch_loop(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
                pidx0, pos0, j_real, limit, eps, *, kind: str, policy: str,
                lookahead: bool, use_limit: bool, max_steps: int):
     """Traceable core: run one allocation epoch entirely under lax control
-    flow.  Returns ``(ns, js, count, X, tot, FREE, used, pidx, pos)``.
+    flow.  Returns ``(ns, js, count, X, tot, FREE, used, pidx, pos,
+    steps)``, ``steps`` the loop's iteration count.
 
     All array arguments may be padded; padded frameworks must carry
     ``wanted == 0`` / ``allowed == False`` and padded servers ``FREE == 0``
@@ -318,6 +336,24 @@ def epoch_loop(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
     ``pos0`` (rows beyond the budget repeat the last — the host wrapper
     detects that from the returned ``pidx`` and re-runs with a bigger
     budget, see :func:`run_epoch`).
+
+    A grant at (n, j) changes the (N, J) state in column j only, and row
+    n's total, so no step makes a pass over the whole matrix: every
+    reduction a select needs is carried as an exact partial updated from
+    that column and row.  ``fcnt`` counts each row's feasible columns (the
+    DRF/TSF row select and the loop's liveness), ``ccnt`` each column's
+    feasible rows (RRR).  A row that stops wanting is not cleared in
+    ``feas``; its counts drop to zero and the selects ignore it.  Under
+    PS-DSF / rPS-DSF a pair's score is ``s[n] * dom[n, j]``, computed where
+    it is read (the same f32 product the scores were stored as), so row n's
+    refresh is one scalar; ``bmin`` holds each row's least feasible share
+    over each block of :func:`block_width` columns, and a block's least
+    score is ``s[n]`` times it, since rounding is monotone and ``s >= 0``.
+    The pooled pick is then the first row-major (row, block) whose least
+    score ties the global minimum, and the first tying column inside that
+    block: the same first flat index as :func:`_argmin_tie_low` over the
+    whole matrix, since an f32 min is exact and the flat order is (row,
+    block, column).
     """
     global TRACE_COUNT
     TRACE_COUNT += 1
@@ -351,12 +387,12 @@ def epoch_loop(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
         dom0 = jnp.zeros((1, 1), f32)
     elif kind == "psdsf":
         dom0 = criteria.virtual_dominant(D, C, xp=jnp)        # (N, J)
-        s0 = ((tot + la) / phi)[:, None] * dom0
+        s0 = (tot + la) / phi
         cap0 = jnp.zeros((1, 1), f32)
     elif kind == "rpsdsf":
         cap0 = criteria.residual_capacities(X, D, C, xp=jnp)  # (J, R)
         dom0 = criteria.virtual_dominant(D, cap0, xp=jnp)     # (N, J)
-        s0 = ((tot + la) / phi)[:, None] * dom0
+        s0 = (tot + la) / phi
     else:
         raise ValueError(f"unsupported criterion kind {kind!r}")
 
@@ -365,18 +401,58 @@ def epoch_loop(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
     if use_limit:
         feas0 = feas0 & (used < limit)[None, :]
 
+    i32 = jnp.int32
+    arangeJ = jnp.arange(J, dtype=i32)
+    blocks = policy == "pooled" and server_specific
+    if blocks:
+        # block b covers columns [b B, b B + B); a step reads a block as the
+        # W = min(B, J) columns from `start`, which only the last block of
+        # a J that B does not divide moves left of b B
+        B = block_width(J)
+        nb = -(-J // B)
+        W = min(B, J)
+        ragged = J > B and J % B != 0
+
+        def _block_cols(b):
+            start = jnp.clip(b * B, 0, J - W)
+            cols = start + jnp.arange(W, dtype=i32)
+            return start, cols, (cols >= b * B) if ragged else True
+
+        def _block_of_rows(dom, feas, wants, b):
+            """(N,) least dominant share of every row over its feasible
+            columns of block b (``_BIG`` for none, or a row that stops
+            wanting)."""
+            start, _cols, ok = _block_cols(b)
+            db = jax.lax.dynamic_slice_in_dim(dom, start, W, axis=1)
+            fb = jax.lax.dynamic_slice_in_dim(feas, start, W, axis=1)
+            return jnp.min(jnp.where(fb & wants[:, None] & ok, db, _BIG),
+                           axis=1)
+
     def _select(st: _EpochState):
         if policy == "pooled":
             if server_specific:
-                flat = _argmin_tie_low(st.s.reshape(-1), st.feas.reshape(-1))
-                return flat // J, flat % J, st.pidx, st.pos
-            row_ok = jnp.any(st.feas, axis=1)
-            n = _argmin_tie_low(st.s, row_ok)
-            j = jnp.min(jnp.where(st.feas[n],
-                                  jnp.arange(J, dtype=jnp.int32), _IBIG))
+                # a block's least score is its least share times the row's
+                # factor: rounding is monotone and the factor >= 0
+                masked = jnp.where(st.bmin < _BIG, st.s[None, :] * st.bmin,
+                                   _BIG)
+                thr = _tie_threshold(jnp.min(masked))
+                # row-major (row, block) key of each block minimum
+                key = (jax.lax.broadcasted_iota(i32, masked.shape, 1) * nb
+                       + jax.lax.broadcasted_iota(i32, masked.shape, 0))
+                first = jnp.min(jnp.where(masked <= thr, key, _IBIG))
+                n, b = first // nb, first % nb
+                start, cols, ok = _block_cols(b)
+                sb = st.s[n] * jax.lax.dynamic_slice(st.dom, (n, start),
+                                                     (1, W))[0]
+                fb = jax.lax.dynamic_slice(st.feas, (n, start), (1, W))[0]
+                hit = fb & (sb <= thr) & ok
+                return n, jnp.min(jnp.where(hit, cols, _IBIG)), st.pidx, \
+                    st.pos
+            n = _argmin_tie_low(st.s, st.fcnt > 0)
+            j = jnp.min(jnp.where(st.feas[n], arangeJ, _IBIG))
             return n, j, st.pidx, st.pos
         if policy == "bestfit":
-            n = _argmin_tie_low(st.s, jnp.any(st.feas, axis=1))
+            n = _argmin_tie_low(st.s, st.fcnt > 0)
             u2, v = bestfit_keys(st.FREE.astype(jnp.int32), TDi[n])
             return n, bestfit_argmin(u2, v, st.feas[n]), st.pidx, st.pos
         # rrr: visit the first feasible server at-or-after `pos` in the
@@ -385,10 +461,9 @@ def epoch_loop(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
         # position of a round also consumes a fresh permutation — both rules
         # mirror the numpy RRRPolicy's rng consumption exactly.
         K = perms.shape[0]
-        arangeJ = jnp.arange(J, dtype=jnp.int32)
         perm = perms[jnp.minimum(st.pidx, K - 1)]
         rank = jnp.zeros(J, jnp.int32).at[perm].set(arangeJ)
-        server_ok = jnp.any(st.feas, axis=0)
+        server_ok = st.ccnt > 0
         ahead = server_ok & (rank >= st.pos)
         wrap = ~jnp.any(ahead)
         perm2 = perms[jnp.minimum(st.pidx + 1, K - 1)]
@@ -396,8 +471,8 @@ def epoch_loop(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
         eff_rank = jnp.where(wrap, rank2, rank)
         eff_ok = jnp.where(wrap, server_ok, ahead)
         j = jnp.argmin(jnp.where(eff_ok, eff_rank, _IBIG))
-        col = st.s[:, j] if server_specific else st.s
-        n = _argmin_tie_low(col, st.feas[:, j])
+        col = st.s * st.dom[:, j] if server_specific else st.s
+        n = _argmin_tie_low(col, st.feas[:, j] & (st.tot < wanted))
         krank = eff_rank[j]
         last = krank == j_real - 1
         pidx = st.pidx + wrap.astype(jnp.int32) + last.astype(jnp.int32)
@@ -412,21 +487,17 @@ def epoch_loop(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
             return st.cap, st.dom, st.s.at[n].set(xt_n * unit[n] / phi[n])
         if kind == "tsf":
             return st.cap, st.dom, st.s.at[n].set(xt_n / denom[n])
+        s = st.s.at[n].set(xt_n / phi[n])
         if kind == "psdsf":
-            return st.cap, st.dom, st.s.at[n].set(xt_n / phi[n] * dom0[n])
-        # rpsdsf: only server j's residual changed -> refresh column j,
-        # then row n (its total changed).
+            return st.cap, st.dom, s
+        # rpsdsf: only server j's residual changed -> refresh column j
         cap_j = C[j] - st.X[:, j] @ D                       # (R,)
         cap = st.cap.at[j].set(cap_j)
         dom_col = criteria.virtual_dominant(D, cap_j[None, :], xp=jnp)[:, 0]
-        dom = st.dom.at[:, j].set(dom_col)
-        xt = st.tot + la
-        s = st.s.at[:, j].set(xt / phi * dom[:, j])
-        s = s.at[n].set(xt_n / phi[n] * dom[n])
-        return cap, dom, s
+        return cap, st.dom.at[:, j].set(dom_col), s
 
     def cond(st: _EpochState):
-        return jnp.any(st.feas) & (st.count < max_steps)
+        return jnp.any(st.fcnt > 0) & (st.count < max_steps)
 
     def body(st: _EpochState):
         n, j, pidx, pos = _select(st)
@@ -436,33 +507,56 @@ def epoch_loop(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
         FREE2 = st.FREE.at[j].add(-bundle)
         used2 = st.used.at[j].add(1)
         st2 = st._replace(X=X2, tot=tot2, FREE=FREE2, used=used2)
-        # feasibility: column j saw FREE change; row n may have hit `wanted`
+        # feasibility: column j saw FREE change.  A row that stops wanting
+        # is not cleared: a row's entries count only while it wants.
         wants = tot2 < wanted
+        wants_n = wants[n]
         col = wants & allowed[:, j] & jnp.all(TD <= FREE2[j][None, :] + eps,
                                               axis=1)
         if use_limit:
             col = col & (used2[j] < limit)
+        old_col = st.feas[:, j] & (st.tot < wanted)
         feas = st.feas.at[:, j].set(col)
-        feas = jnp.where((jnp.arange(X2.shape[0]) == n)[:, None] & ~wants[n],
-                         False, feas)
         cap, dom, s = _refresh(st2, n, j)
+        # the partials, from column j and row n alone (col[n] is already
+        # False when row n stops wanting, so nothing is counted twice)
+        fcnt = st.fcnt + col.astype(i32) - old_col.astype(i32)
+        fcnt = fcnt.at[n].set(jnp.where(wants_n, fcnt[n], 0))
+        ccnt = bmin = None
+        if policy == "rrr":
+            ccnt = (st.ccnt - (feas[n] & ~wants_n).astype(i32)).at[j].set(
+                jnp.sum(col, dtype=i32))
+        if blocks:
+            bj = j // B
+            bmin = st.bmin.at[bj].set(_block_of_rows(dom, feas, wants, bj))
+            bmin = bmin.at[:, n].set(jnp.where(wants_n, bmin[:, n], _BIG))
         return _EpochState(
             X=X2, tot=tot2, FREE=FREE2, cap=cap, dom=dom, s=s, feas=feas,
-            used=used2, pidx=pidx, pos=pos, count=st.count + 1,
+            used=used2, fcnt=fcnt, ccnt=ccnt, bmin=bmin, pidx=pidx, pos=pos,
+            count=st.count + 1,
             ns=st.ns.at[st.count].set(n.astype(jnp.int32)),
             js=st.js.at[st.count].set(j.astype(jnp.int32)),
         )
 
+    if blocks:
+        masked0 = jnp.pad(jnp.where(feas0, dom0, _BIG),
+                          ((0, 0), (0, nb * B - J)), constant_values=_BIG)
+        bmin0 = jnp.min(masked0.reshape(N, nb, B), axis=2).T
     init = _EpochState(
         X=X, tot=tot, FREE=FREE, cap=cap0, dom=dom0, s=s0, feas=feas0,
-        used=used.astype(jnp.int32), pidx=jnp.asarray(pidx0, jnp.int32),
-        pos=jnp.asarray(pos0, jnp.int32), count=jnp.int32(0),
-        ns=jnp.full((max_steps,), -1, jnp.int32),
-        js=jnp.full((max_steps,), -1, jnp.int32),
+        used=used.astype(i32), fcnt=jnp.sum(feas0, axis=1, dtype=i32),
+        ccnt=(jnp.sum(feas0, axis=0, dtype=i32) if policy == "rrr"
+              else None),
+        bmin=bmin0 if blocks else None,
+        pidx=jnp.asarray(pidx0, i32), pos=jnp.asarray(pos0, i32),
+        count=i32(0),
+        ns=jnp.full((max_steps,), -1, i32),
+        js=jnp.full((max_steps,), -1, i32),
     )
     fin = jax.lax.while_loop(cond, body, init)
+    # one grant per step, so the steps are the grants
     return (fin.ns, fin.js, fin.count, fin.X, fin.tot, fin.FREE, fin.used,
-            fin.pidx, fin.pos)
+            fin.pidx, fin.pos, fin.count)
 
 
 class _MeshState(NamedTuple):
@@ -795,8 +889,10 @@ def epoch_loop_mesh(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
             js=jnp.full((max_steps,), -1, i32),
         )
         fin = jax.lax.while_loop(cond, body, init)
+        # iterations: the grants, and the probe that found none, if any ran
+        steps = fin.count + (~fin.alive).astype(i32)
         return (fin.ns, fin.js, fin.count, fin.X, fin.tot, fin.FREE,
-                fin.used, fin.pidx, fin.pos)
+                fin.used, fin.pidx, fin.pos, steps)
 
     P = PartitionSpec
     shard_j = P(None, "agents")      # (N, J) blocks, server axis sharded
@@ -812,7 +908,7 @@ def epoch_loop_mesh(X, D, TD, C, FREE, phi, wanted, allowed, perms, used,
                   rep, rep, rep, rep, rep, rep, rep, rep, rep, rep, rep,
                   rep),
         out_specs=(rep, rep, rep, shard_j, rep, shard_row, P("agents"),
-                   rep, rep),
+                   rep, rep, rep),
         check_vma=False,
     )
     return fn(X, FREE, cap0, dom0, s0, feas0, allowed, C,
@@ -1069,8 +1165,8 @@ class _EpochRun:
     def _finish(self) -> list[tuple[int, int]]:
         out: list[tuple[int, int]] = []
         while True:
-            ns, js, count, Xd, _totd, FREEd, usedd, pidx_d, pos_d = \
-                self._wait()
+            (ns, js, count, Xd, _totd, FREEd, usedd, pidx_d, pos_d,
+             steps) = self._wait()
             if self.policy == "rrr":
                 # a clamped permutation read implies the final cursor ran
                 # past the stack (every used row index is <= the final
@@ -1090,10 +1186,11 @@ class _EpochRun:
                                           _put(useds, jnp.int32))
                         else:
                             self.dispatch(*self._last_inputs)
-                    ns, js, count, Xd, _totd, FREEd, usedd, pidx_d, pos_d = \
-                        self._wait()
+                    (ns, js, count, Xd, _totd, FREEd, usedd, pidx_d, pos_d,
+                     steps) = self._wait()
             with tracing.span("engine_jax.readback"):
-                k = int(count)
+                k, n_steps = (int(v) for v in jax.device_get((count, steps)))
+                tracing.count("engine_jax.loop_steps", n_steps)
                 out.extend(zip(np.asarray(ns[:k]).tolist(),
                                np.asarray(js[:k]).tolist()))
                 if k < self.max_steps or self.remaining - k <= 0:

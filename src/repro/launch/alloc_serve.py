@@ -273,6 +273,7 @@ class AllocatorService:
         tr = _tracing.totals()
         out["upload_bytes"] = tr.get("engine_jax.upload_bytes", 0)
         out["staged_nonzeros"] = tr.get("engine_jax.staged_nonzeros", 0)
+        out["loop_steps"] = tr.get("engine_jax.loop_steps", 0)
         out["hashed_bytes"] = tr.get("epoch_cache.hashed_bytes", 0)
         out["lowerings"] = tr.get("jax.lowerings", 0)
         out["compile_s"] = tr.get("jax.compile_s", 0.0)

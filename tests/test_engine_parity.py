@@ -295,6 +295,193 @@ def test_device_epoch_chaining_and_perm_growth_keep_parity(crit, pol):
         assert fused(max_steps_cap=16, _perm_rows=2) == order_np
 
 
+#: every criterion x server policy the device epoch serves
+DEVICE_COMBOS = ([(c, p) for c in CRITERIA for p in DEVICE_POLICIES]
+                 + [("drf", "bestfit"), ("tsf", "bestfit")])
+
+
+def _partials_case(name):
+    """An epoch built against the device loop's carried select partials,
+    with the ``per_agent_limit`` it runs under.  Whole-unit capacities and
+    demands (best-fit needs them; the scores are then as exact in f32 as
+    in float64).  The padded J is 256, two select blocks of 128 columns,
+    except where the case says otherwise."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    N, J, limit = 6, 256, None
+    C = np.full((J, 2), 16.0)
+    TD = np.ones((N, 2))
+    X = np.zeros((N, J))
+    phi = np.ones(N)
+    allowed = np.ones((N, J), bool)
+    wanted = np.full(N, 8.0)
+    if name == "ties-at-block-edge":
+        # everything ties at the start; row 0 only past the block edge,
+        # row 1 on both sides of it and before row 0's columns, so the
+        # first row-major index must win over the first block
+        allowed[:3] = False
+        allowed[0, [130, 250]] = True
+        allowed[1, [5, 127, 128]] = True
+        allowed[2, [127, 128, 129]] = True
+        wanted[:3] = (4.0, 4.0, 6.0)
+    elif name == "unique-min-rises":
+        # each row holds one executor, so scores start apart from zero;
+        # one large machine per block is every row's only minimum and
+        # rises with each grant it takes
+        C[[60, 200]] = (64.0, 64.0)
+        TD = rng.integers(1, 3, (N, 2)).astype(float)
+        X[np.arange(N), np.arange(N)] = 1.0
+        phi = np.array([1.0, 2.0, 1.0, 0.5, 1.0, 2.0])
+        wanted = X.sum(axis=1) + 12.0
+    elif name in ("exhaustion", "limit"):
+        # rows run out of wants, and row 0 out of machines with room, mid-
+        # epoch (and, under the limit, machines out of grants)
+        C = rng.choice([4.0, 8.0, 12.0], (J, 2))
+        TD = rng.integers(1, 5, (N, 2)).astype(float)
+        wanted = rng.integers(1, 7, N).astype(float)
+        wanted[0] = 1000.0
+        allowed = rng.random((N, J)) < 0.3
+        if name == "limit":
+            limit = 1
+    elif name == "small-J":
+        # padded J (64) below one block
+        J = 40
+        X = np.zeros((N, J))
+        C = rng.choice([8.0, 16.0], (J, 2))
+        TD = rng.integers(1, 4, (N, 2)).astype(float)
+        allowed = rng.random((N, J)) < 0.7
+    else:
+        raise ValueError(name)
+    FREE = C - X.T @ TD
+    return dict(X=X, D=TD, C=C, FREE=FREE, phi=phi, allowed=allowed,
+                wanted=wanted, true_demands=TD), limit
+
+
+PARTIALS_CASES = ("ties-at-block-edge", "unique-min-rises", "exhaustion",
+                  "limit", "small-J")
+
+
+def _numpy_epoch(crit, pol, inst, limit, seed):
+    """The numpy ``BatchedEpoch``'s grant sequence over one epoch."""
+    from repro.core.engine import BatchedEpoch
+
+    ep = BatchedEpoch(crit, pol, rng=np.random.default_rng(seed),
+                      per_agent_limit=limit, **inst)
+    seq = []
+    while (pick := ep.select()) is not None:
+        seq.append(pick)
+        ep.apply(*pick, inst["true_demands"][pick[0]])
+    return seq
+
+
+@pytest.mark.parametrize("case", PARTIALS_CASES)
+@pytest.mark.parametrize("crit,pol", DEVICE_COMBOS)
+def test_device_epoch_partials_match_numpy_batched(case, crit, pol):
+    """The device loop's carried partials (per-row and per-column feasible
+    counts, per-block row minima) pick exactly what the numpy engine's
+    selects pick: ties on both sides of a block edge and across rows, a
+    row whose only minimum is the granted column and rises there, rows and
+    columns exhausted mid-epoch, a J below one block."""
+    pytest.importorskip("jax")
+    from repro.core import engine_jax
+
+    inst, limit = _partials_case(case)
+    want = _numpy_epoch(crit, pol, inst, limit, seed=4)
+    got = engine_jax.run_epoch(crit, pol, rng=np.random.default_rng(4),
+                               per_agent_limit=limit, **inst)
+    assert len(want) > 10 and got == want
+
+
+@pytest.mark.parametrize("crit,pol", DEVICE_COMBOS)
+def test_device_epoch_partials_on_a_ragged_block(crit, pol):
+    """``epoch_loop`` run directly at an unpadded J = 200, which one block
+    of 128 columns does not divide: the last block is read from column 72
+    and masked to its own 72 columns.  It equals the numpy engine."""
+    pytest.importorskip("jax")
+    import jax.numpy as jnp
+
+    from repro.core import engine_jax
+
+    rng = np.random.default_rng(200)
+    N, J = 7, 200
+    assert J % engine_jax.block_width(J) != 0 < J - engine_jax.block_width(J)
+    C = rng.choice([8.0, 16.0, 32.0], (J, 2))
+    C[[127, 128, 199]] = 64.0                # minima at the block edges
+    TD = rng.integers(1, 4, (N, 2)).astype(float)
+    inst = dict(X=np.zeros((N, J)), D=TD, C=C, FREE=C.copy(),
+                phi=np.array([1.0, 2.0, 1.0, 0.5, 1.0, 2.0, 1.0]),
+                allowed=rng.random((N, J)) < 0.8,
+                wanted=rng.integers(4, 30, N).astype(float), true_demands=TD)
+    want = _numpy_epoch(crit, pol, inst, None, seed=5)
+    stream = np.random.default_rng(5)
+    perms = np.stack([stream.permutation(J) for _ in range(64)])
+    f32 = jnp.float32
+    out = engine_jax._jitted(False)(
+        jnp.asarray(inst["X"], f32), jnp.asarray(TD, f32),
+        jnp.asarray(TD, f32), jnp.asarray(C, f32), jnp.asarray(C, f32),
+        jnp.asarray(inst["phi"], f32), jnp.asarray(inst["wanted"], f32),
+        jnp.asarray(inst["allowed"]), jnp.asarray(perms, jnp.int32),
+        jnp.zeros(J, jnp.int32), np.int32(0), np.int32(0), jnp.int32(J),
+        np.int32(0), f32(1e-9), kind=crit, policy=pol, lookahead=False,
+        use_limit=False, max_steps=256)
+    ns, js, count, pidx = out[0], out[1], int(out[2]), int(out[7])
+    assert pidx < len(perms) and count < 256
+    got = list(zip(np.asarray(ns[:count]).tolist(),
+                   np.asarray(js[:count]).tolist()))
+    assert len(want) > 10 and got == want
+
+
+#: the (criterion, policy) pairs the benchmark's cells serve
+SERVED = (("rpsdsf", "pooled"), ("drf", "bestfit"), ("drf", "rrr"))
+#: primitives that read part of a matrix or write part of it in place
+_PART_PRIMS = {"dynamic_slice", "dynamic_update_slice", "gather", "scatter",
+               "scatter-add"}
+
+
+@pytest.mark.parametrize("kind,policy", SERVED)
+def test_device_epoch_step_makes_no_whole_matrix_pass(kind, policy):
+    """No step of the device loop reads the whole (N, J) state: inside the
+    ``while``'s body and condition, every operation with an operand of
+    N J elements only slices it or updates part of it in place, so no
+    reduction, select or elementwise pass of the full matrix comes back
+    into a step unnoticed.  Traced at the fill cells' (2048, 16384)."""
+    jax = pytest.importorskip("jax")
+    import functools
+
+    import jax.numpy as jnp
+
+    from repro.core import engine_jax
+
+    N, J, R = 2048, 16384, 2
+    f32, i32 = jnp.float32, jnp.int32
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    rows = engine_jax.rrr_perm_budget(16384, J) if policy == "rrr" else 1
+    closed = jax.make_jaxpr(functools.partial(
+        engine_jax.epoch_loop, kind=kind, policy=policy, lookahead=False,
+        use_limit=True, max_steps=16384))(
+        S((N, J), f32), S((N, R), f32), S((N, R), f32), S((J, R), f32),
+        S((J, R), f32), S((N,), f32), S((N,), f32), S((N, J), jnp.bool_),
+        S((rows, J), i32), S((J,), i32), S((), i32), S((), i32),
+        S((), i32), S((), i32), S((), f32))
+    (loop,) = [e for e in closed.jaxpr.eqns if e.primitive.name == "while"]
+
+    def passes(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name not in _PART_PRIMS and any(
+                    getattr(v.aval, "size", 0) >= N * J
+                    for v in eqn.invars):
+                out.append(eqn.primitive.name)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                passes(sub, out)
+        return out
+
+    found = passes(loop.params["body_jaxpr"].jaxpr, [])
+    found = passes(loop.params["cond_jaxpr"].jaxpr, found)
+    assert not found, found
+
+
 def test_batched_epoch_respects_per_agent_limit():
     al = OnlineAllocator(2, criterion="drf", server_policy="rrr", seed=0)
     for j in range(4):

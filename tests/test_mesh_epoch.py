@@ -12,7 +12,7 @@ Parity contracts pinned here:
   * mid-epoch exhaustion — small ``wanted`` budgets and
     ``per_agent_limit`` stop the mesh loop at exactly the reference grant
     count (the select's found-flag liveness, not the single-device loop's
-    full-matrix ``any(feas)`` guard);
+    per-row feasible counts);
   * retrace discipline — a mesh (shape, devices) key retraces at most
     once; repeats reuse the cached executable.
 """

@@ -344,3 +344,32 @@ def test_service_counters_report_the_fingerprint_counters(recorder):
              if r.name == "epoch_cache.fingerprint"]
     c = service.counters()
     assert c["hashed_bytes"] == fp.attrs["epoch_cache.hashed_bytes"] > 0
+
+
+@pytest.mark.parametrize("policy,criterion", [("rrr", "drf"),
+                                              ("pooled", "rpsdsf"),
+                                              ("bestfit", "drf")])
+def test_the_readback_counts_the_device_loop_steps(recorder, policy,
+                                                   criterion):
+    """One loop step per grant: ``engine_jax.loop_steps`` on each
+    readback span is that dispatch's grants, over one dispatch and over
+    chained ones, and the service reports the total as ``loop_steps``."""
+    from repro.core import engine_jax
+
+    service = _service(policy, criterion)
+    grants = _epoch(service, 5, 3)
+    (rb,) = [r for r in tracing.records() if r.name == "engine_jax.readback"]
+    assert rb.attrs["engine_jax.loop_steps"] == len(grants) == 15
+    assert service.counters()["loop_steps"] == len(grants)
+
+    tracing.reset()
+    caps = np.tile([[16.0, 64.0]], (10, 1))
+    seq = engine_jax.run_epoch(
+        criterion, policy, X=np.zeros((3, 10)), D=np.ones((3, 2)), C=caps,
+        FREE=caps.copy(), phi=np.ones(3), allowed=np.ones((3, 10), bool),
+        wanted=np.array([9.0, 12.0, 20.0]), true_demands=np.ones((3, 2)),
+        rng=np.random.default_rng(0), max_steps_cap=16)
+    steps = [r.attrs["engine_jax.loop_steps"] for r in tracing.records()
+             if r.name == "engine_jax.readback"]
+    assert len(seq) == 41 and len(steps) == 3 and sum(steps) == len(seq)
+    assert tracing.totals()["engine_jax.loop_steps"] == len(seq)
